@@ -149,6 +149,9 @@ def bench_pdgemm_ft(results, quick, bench_dir):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
                         + env.get("XLA_FLAGS", "")).strip()
+    # a CPU rehearsal on forced host devices: the parent may hold the
+    # chip, so the child must not reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.abspath(os.path.join(bench_dir, "..", "src"))
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", code], env=env,

@@ -204,6 +204,9 @@ def check_pdgemm(n: int = 64, nb: int = 16) -> int:
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
                         + env.get("XLA_FLAGS", "")).strip()
+    # a CPU rehearsal on forced host devices: the parent may hold the
+    # chip, so the child must not reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -17,17 +17,25 @@ adapted to the TPU execution model:
 
 Two backends share one public API:
   * ``backend="exact"`` — int64 significand arithmetic, the ground truth.
-  * ``backend="fast"``  — decode to float64 (exact: p32e2 has <= 28-bit
-    significands and |scale| <= 120), operate in f64, re-round.  Mul is still
-    bit-exact (<= 56-bit products are exact in f64); add/div admit a
-    double-rounding corner with probability ~2^-26 per op, which is
-    immaterial for the accuracy *benchmarks* (they measure digits of backward
-    error).  The property tests pin the exact backend against a pure-Python
-    rational-arithmetic oracle.
+  * ``backend="fast"``  — binary64 semantics: the operation is rounded to
+    binary64 (RNE, 53 bits) and then to the posit lattice, as decoding to
+    f64, operating and re-rounding would.  A p32e2 product needs up to 56
+    bits, so mul, like add and div, admits a double-rounding corner with
+    probability ~2^-26 per op, which is immaterial for the accuracy
+    *benchmarks* (they measure digits of backward error).  The property
+    tests pin the exact backend against a pure-Python rational-arithmetic
+    oracle, and the fast backend against real f64 arithmetic.
+
+The fast backend and the fused-chain helpers run binary64 **in integer
+fields** (section "binary64 in integer fields"): a TPU has no f64 unit,
+its compiler refuses f64 bit casts, and its f64 emulation is not IEEE
+exact.  ``to_float64`` / ``from_float64`` / ``chain_round`` keep the real
+f64 implementations as the reference the tests compare against.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -338,16 +346,16 @@ def to_float32(p, fmt: PositFormat = P32E2):
 
 def pconvert(p, src: PositFormat, dst: PositFormat):
     """Posit -> posit format conversion, correctly rounded (RNE on the
-    destination pattern boundary).  Exact decode (every supported posit is
-    f64-representable: <= 28-bit significands, |scale| <= 120) followed by
-    one correctly-rounded encode, so widening (e.g. p16e1 -> p32e2) is
-    exact and narrowing rounds once.  NaR maps to NaR, zero to zero.
+    destination pattern boundary).  Exact decode (every supported posit
+    fits the 53-bit field significand) followed by one correctly-rounded
+    encode, so widening (e.g. p16e1 -> p32e2) is exact and narrowing
+    rounds once.  NaR maps to NaR, zero to zero.  Integer fields only.
     The mixed-precision IR solvers (lapack/refine.py rgesv_mp) perform
     this same decode-scale-encode dance with a power-of-two equilibration
     folded between the two halves — see refine.mp_narrow_matrix."""
     if src is dst:
         return jnp.asarray(p, jnp.int32)
-    return from_float64(to_float64(p, src), dst)
+    return _soft_encode(_soft_from_posit(p, src), dst)
 
 
 def from_float32(x, fmt: PositFormat = P32E2):
@@ -355,22 +363,256 @@ def from_float32(x, fmt: PositFormat = P32E2):
 
 
 # --------------------------------------------------------------------------
-# fast backend (f64 emulation) + public dispatch
+# binary64 in integer fields — the device path
+#
+# A binary64 value is carried as its IEEE-754 bit pattern in int64 (the
+# ``*_bits`` conversions and the fused-chain values) or unpacked into a
+# ``_Soft`` tuple of fields.  + - * / sqrt round to nearest-even at 53
+# bits exactly as IEEE f64 hardware does, using int64 integer ops only,
+# so a TPU (no f64 unit; its compiler refuses f64 bit casts and its f64
+# emulation is not IEEE-exact) computes the same words as a CPU's f64.
+# Operands of * / sqrt must carry <= 28 significant bits (posit words and
+# f32 values do); + takes any 53-bit operands.  Every value this module
+# produces lies in [2^-250, 2^250], far inside binary64's normal range,
+# so no subnormal, overflow or infinity handling is needed; NaN covers
+# NaR, x/0 and sqrt of a negative.
+# --------------------------------------------------------------------------
+
+_B = 52                                   # binary64 fraction bits
+_FRAC_MASK = (1 << _B) - 1
+_NAN_BITS = 0x7FF8000000000000            # jnp.nan's pattern
+_INF_BITS = 0x7FF0000000000000
+_SIGN_BITS = -(1 << 63)                   # int64 with only bit 63 set
+
+
+class _Soft(NamedTuple):
+    """Unpacked binary64: value = (-1)^sign * sig * 2^(scale - 52) with
+    sig in [2^52, 2^53); ``sig`` is 2^52 on zero/NaN lanes so shifts of
+    dead lanes stay in range."""
+    nan: jax.Array
+    zero: jax.Array
+    sign: jax.Array
+    scale: jax.Array
+    sig: jax.Array
+
+
+def _soft_where(c, x: _Soft, y: _Soft) -> _Soft:
+    return _Soft(*(jnp.where(c, a, b) for a, b in zip(x, y)))
+
+
+def _soft_from_posit(p, fmt: PositFormat) -> _Soft:
+    is_zero, nar, sign, scale, sig = decode(p, fmt)
+    return _Soft(nar, is_zero, sign & ~is_zero, scale, sig << (_B - _F))
+
+
+def _soft_from_f32(x) -> _Soft:
+    """f32 values -> fields, exactly; subnormals read as zero, as XLA's
+    f32 -> f64 conversion reads them."""
+    bits = jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.float32),
+                                        jnp.int32)
+    expf = ((bits >> 23) & 0xFF).astype(_I64)
+    zero = expf == 0
+    sig = ((bits & 0x7FFFFF).astype(_I64) | (1 << 23)) << (_B - 23)
+    return _Soft(expf == 0xFF, zero, (bits < 0) & ~zero, expf - 127, sig)
+
+
+def _soft_from_bits(x) -> _Soft:
+    """binary64 patterns (int64) -> fields.  Subnormals read as zero, as
+    XLA's flush-to-zero f64 arithmetic reads them."""
+    x = _i64(x)
+    ex = (x >> _B) & 0x7FF
+    zero = ex == 0
+    return _Soft(ex == 0x7FF, zero, (x < 0) & ~zero, ex - 1023,
+                 (x & _FRAC_MASK) | (_i64(1) << _B))
+
+
+def _soft_to_bits(v: _Soft):
+    bits = ((v.scale + 1023) << _B) | (v.sig & _FRAC_MASK)
+    bits = jnp.where(v.sign, bits | _SIGN_BITS, bits)
+    bits = jnp.where(v.zero, _i64(0), bits)
+    return jnp.where(v.nan, _i64(_NAN_BITS), bits)
+
+
+def _soft_round(nan, zero, sign, mag, e0) -> _Soft:
+    """Round mag * 2^e0 (mag > 0 on live lanes) to 53 bits, RNE.  A caller
+    whose mag is inexact jams the sticky bit into bit 0 and guarantees at
+    least two dropped bits (mag >= 2^54), which makes the jam exact."""
+    live = ~(nan | zero)
+    mag = jnp.where(live, mag, _i64(1) << _B)
+    msb = floor_log2(mag)
+    r = jnp.maximum(msb - _B, 0)
+    sig = jnp.where(msb > _B, mag >> r, mag << jnp.maximum(_B - msb, 0))
+    rem = mag & ((_i64(1) << r) - 1)
+    half = (_i64(1) << r) >> 1
+    up = (r > 0) & ((rem > half) | ((rem == half) & ((sig & 1) == 1)))
+    sig = sig + up.astype(_I64)
+    carry = sig >> (_B + 1)                               # 0, or 1 at 2^53
+    return _Soft(nan, zero & ~nan, sign, msb + e0 + carry, sig >> carry)
+
+
+def _soft_neg(v: _Soft) -> _Soft:
+    return v._replace(sign=~v.sign & ~v.zero)
+
+
+def _soft_add(a: _Soft, b: _Soft) -> _Soft:
+    swap = (b.scale > a.scale) | ((b.scale == a.scale) & (b.sig > a.sig))
+    hi = _soft_where(swap, b, a)
+    lo = _soft_where(swap, a, b)
+    sh = jnp.minimum(hi.scale - lo.scale, 63)
+    big = hi.sig << 9                                     # [2^61, 2^62)
+    small = lo.sig << 9
+    lost = (small & ((_i64(1) << sh) - 1)) != 0           # only if sh > 9
+    small = (small >> sh) | lost.astype(_I64)
+    mag = jnp.where(hi.sign != lo.sign, big - small, big + small)
+    nan = a.nan | b.nan
+    out = _soft_round(nan, mag == 0, hi.sign & (mag != 0), mag,
+                      hi.scale - _B - 9)
+    out = _soft_where(a.zero & ~nan, b, out)              # 0 + y == y
+    return _soft_where(b.zero & ~a.zero & ~nan, a, out)
+
+
+def _soft_mul(a: _Soft, b: _Soft) -> _Soft:
+    nan = a.nan | b.nan
+    mag = (a.sig >> (_B - _F)) * (b.sig >> (_B - _F))    # [2^54, 2^56)
+    return _soft_round(nan, a.zero | b.zero, a.sign ^ b.sign, mag,
+                       a.scale + b.scale - 2 * _F)
+
+
+def _soft_div(a: _Soft, b: _Soft) -> _Soft:
+    """Quotient by restoring long division: 63 quotient bits plus a jammed
+    sticky bit.  The remainder stays below 2^29, so the loop is int32 (a
+    TPU has no integer divide, and its emulated int64 one is slow)."""
+    den = (b.sig >> (_B - _F)).astype(jnp.int32)          # [2^27, 2^28)
+    rem = (a.sig >> (_B - _F)).astype(jnp.int32)
+    halves = []
+    for width in (31, 32):                                # Q bits 62..0
+        q = jnp.zeros_like(rem)
+        for _ in range(width):
+            bit = rem >= den
+            rem = jnp.where(bit, rem - den, rem)
+            q = (q << 1) | bit.astype(jnp.int32)
+            rem = rem << 1
+        halves.append(q)
+    hi, lo = halves
+    mag = ((hi.astype(_I64) << 32) | (lo.astype(_I64) & 0xFFFFFFFF)
+           | (rem != 0).astype(_I64))                     # (2^61, 2^63)
+    nan = a.nan | b.nan | b.zero                          # x/0, 0/0: NaN
+    return _soft_round(nan, a.zero, a.sign ^ b.sign, mag,
+                       a.scale - b.scale - 62)
+
+
+def _soft_sqrt(a: _Soft) -> _Soft:
+    """Digit-by-digit integer square root of sig * 2^82: 55-56 root bits
+    plus a jammed sticky bit."""
+    odd = (a.scale - _F) & 1                              # make exp even
+    x = (a.sig >> (_B - _F)) << odd                       # <= 29 bits
+    root = jnp.zeros_like(x)
+    rem = jnp.zeros_like(x)
+    for i in range(55, -1, -1):                           # X = x << 82
+        pos = 2 * i - 82
+        pair = (x >> pos) & 3 if pos >= 0 else jnp.zeros_like(x)
+        rem = (rem << 2) | pair
+        trial = (root << 2) | 1
+        ge = rem >= trial
+        rem = jnp.where(ge, rem - trial, rem)
+        root = (root << 1) | ge.astype(_I64)
+    nan = a.nan | (a.sign & ~a.zero)
+    return _soft_round(nan, a.zero, jnp.zeros_like(a.sign),
+                       root | (rem != 0).astype(_I64),
+                       (a.scale - _F - odd - 82) >> 1)
+
+
+def _soft_encode(v: _Soft, fmt: PositFormat):
+    """Fields -> posit words, correctly rounded (the value is exact)."""
+    return encode(v.sign, v.scale, v.sig, False, v.zero, v.nan, fmt,
+                  width=_B)
+
+
+def _soft_posit_round(v: _Soft, fmt: PositFormat) -> _Soft:
+    """Round fields to the nearest posit *value*, staying in fields — the
+    integer twin of ``chain_round`` (see there for the rounding rule)."""
+    nbits, es = fmt.nbits, fmt.es
+    q = v.sig >> (_B - 28)                                # [2^28, 2^29)
+    sticky = (v.sig & ((_i64(1) << (_B - 28)) - 1)) != 0
+    frac = q & ((_i64(1) << 28) - 1)
+    scale = v.scale
+    k = scale >> es
+    e = scale - (k << es)
+    reg_len = jnp.where(k >= 0, k + 2, 1 - k)
+    ef = (_i64(1) << (es + 28)) | (e << 28) | frac      # [1|e|frac28]
+    d = jnp.clip(29 + es + reg_len - nbits, 1, es + 28)
+    dropped = ef & ((_i64(1) << d) - 1)
+    half = _i64(1) << (d - 1)
+    kept = ef >> d
+    pat_lsb = jnp.where(d == es + 28,
+                        jnp.where(k < 0, _i64(1), _i64(0)), kept & 1)
+    rnd = (dropped > half) | ((dropped == half) & (sticky | (pat_lsb == 1)))
+    q2 = (kept + rnd.astype(_I64)) << d
+    carry = q2 >> (es + 29)
+    k2 = k + carry
+    e2 = jnp.where(carry == 1, 0, (q2 >> 28) & ((_i64(1) << es) - 1))
+    frac2 = jnp.where(carry == 1, 0, q2 & ((_i64(1) << 28) - 1))
+    scale2 = (k2 << es) + e2
+    sig2 = (frac2 + (_i64(1) << 28)) << (_B - 28)
+    over = scale >= fmt.max_scale
+    under = scale < -fmt.max_scale
+    scale2 = jnp.where(over, fmt.max_scale,
+                       jnp.where(under, -fmt.max_scale, scale2))
+    sig2 = jnp.where(over | under, _i64(1) << _B, sig2)
+    return v._replace(scale=scale2, sig=sig2)
+
+
+def to_float64_bits(p, fmt: PositFormat = P32E2):
+    """posit words -> binary64 bit patterns (int64), exact; NaR -> NaN.
+    Bit-identical to ``bitcast(to_float64(p))`` with integer ops only."""
+    return _soft_to_bits(_soft_from_posit(p, fmt))
+
+
+def from_float64_bits(x, fmt: PositFormat = P32E2):
+    """binary64 bit patterns (int64) -> posit words, correctly rounded;
+    the integer-only twin of ``from_float64`` (NaN/inf -> NaR, f64
+    subnormals -> 0 as XLA flushes them).  The host side of a device path
+    passes ``np.asarray(x, np.float64).view(np.int64)``."""
+    return _soft_encode(_soft_from_bits(x), fmt)
+
+
+def word_of(x: float, fmt: PositFormat = P32E2):
+    """A static Python scalar -> its posit word, as an int32 constant
+    computed with integer ops (legal inside any device program)."""
+    return from_float64_bits(np.asarray(x, np.float64).view(np.int64), fmt)
+
+
+def f32_epilogue(acc, alpha_p, beta_p=None, c_p=None,
+                 fmt: PositFormat = P32E2):
+    """Posit words of ``alpha * acc (+ beta * c)`` for an f32 accumulator
+    ``acc`` and posit words alpha, beta, c: each product and the sum are
+    rounded to binary64, then the result once to the posit lattice — the
+    f64 epilogue's words, in integer fields."""
+    out = _soft_mul(_soft_from_posit(alpha_p, fmt), _soft_from_f32(acc))
+    if c_p is not None:
+        out = _soft_add(out, _soft_mul(_soft_from_posit(beta_p, fmt),
+                                       _soft_from_posit(c_p, fmt)))
+    return _soft_encode(out, fmt)
+
+
+# --------------------------------------------------------------------------
+# public dispatch (fast backend = binary64 semantics in integer fields)
 # --------------------------------------------------------------------------
 
 def _fast_binop(op):
     def f(a, b, fmt: PositFormat = P32E2):
-        xa, xb = to_float64(a, fmt), to_float64(b, fmt)
-        return from_float64(op(xa, xb), fmt)
+        return _soft_encode(op(_soft_from_posit(a, fmt),
+                               _soft_from_posit(b, fmt)), fmt)
     return f
 
 
 _FAST = {
-    "add": _fast_binop(jnp.add),
-    "sub": _fast_binop(jnp.subtract),
-    "mul": _fast_binop(jnp.multiply),
-    "div": _fast_binop(jnp.divide),
-    "sqrt": lambda a, fmt=P32E2: from_float64(jnp.sqrt(to_float64(a, fmt)), fmt),
+    "add": _fast_binop(_soft_add),
+    "sub": _fast_binop(lambda x, y: _soft_add(x, _soft_neg(y))),
+    "mul": _fast_binop(_soft_mul),
+    "div": _fast_binop(_soft_div),
+    "sqrt": lambda a, fmt=P32E2: _soft_encode(
+        _soft_sqrt(_soft_from_posit(a, fmt)), fmt),
 }
 
 _EXACT = {
@@ -414,33 +656,87 @@ def sqrt(a, fmt: PositFormat = P32E2, backend: str = "exact"):
 # EVERY call, so a chained update like  col - a*b  (the panel kernels'
 # inner loop) decodes the same entries once per scalar op and round-trips
 # the intermediate product through a posit word it immediately decodes
-# again.  The chain form keeps values in f64 between ops and replaces the
-# word round-trip with ``chain_round`` — round an f64 value to the posit
-# lattice, staying in f64.  Because every posit value is exactly f64-
-# representable (<= 28-bit significands, |scale| <= 120), a chain of
-# {chain_round(op(...))} steps produces bit-for-bit the same values as the
-# corresponding fast-backend word ops: decode once on entry
-# (``chain_decode``), encode once on exit (``chain_encode``).
+# again.  The chain form carries binary64 bit patterns (int64) between
+# ops and rounds each result to the posit lattice in fields: decode once
+# on entry (``chain_decode``), encode once on exit (``chain_encode``).
+# Every chain value is exactly the f64 value the fast backend's words
+# decode to, so a chain of {round(op(...))} steps produces bit-for-bit the
+# words of the corresponding fast-backend word ops.  Chain values are
+# int64 patterns, not numbers: use ``chain_abs``/``chain_neg``/
+# ``chain_isnan``/``chain_positive``/``chain_iszero``/``chain_const``
+# for anything but the arithmetic below.
 # --------------------------------------------------------------------------
 
 def chain_decode(p, fmt: PositFormat = P32E2):
-    """Posit words -> exact f64 values (decode once, at chain entry)."""
-    return to_float64(p, fmt)
+    """Posit words -> exact chain values (decode once, at chain entry)."""
+    return to_float64_bits(p, fmt)
 
 
 def chain_encode(x, fmt: PositFormat = P32E2):
-    """f64 chain values -> posit words (encode once, at chain exit).
-    Exact (no extra rounding) when x is already on the posit lattice,
-    i.e. the output of a chain_* op."""
-    return from_float64(x, fmt)
+    """Chain values -> posit words (encode once, at chain exit).  Exact
+    (no extra rounding) for the output of a chain_* op."""
+    return from_float64_bits(x, fmt)
+
+
+def _chain_out(v: _Soft, fmt: PositFormat):
+    return _soft_to_bits(_soft_posit_round(v, fmt))
+
+
+def chain_add(a, b, fmt: PositFormat = P32E2):
+    return _chain_out(_soft_add(_soft_from_bits(a), _soft_from_bits(b)), fmt)
+
+
+def chain_sub(a, b, fmt: PositFormat = P32E2):
+    return _chain_out(_soft_add(_soft_from_bits(a),
+                                _soft_neg(_soft_from_bits(b))), fmt)
+
+
+def chain_mul(a, b, fmt: PositFormat = P32E2):
+    return _chain_out(_soft_mul(_soft_from_bits(a), _soft_from_bits(b)), fmt)
+
+
+def chain_div(a, b, fmt: PositFormat = P32E2):
+    return _chain_out(_soft_div(_soft_from_bits(a), _soft_from_bits(b)), fmt)
+
+
+def chain_sqrt(a, fmt: PositFormat = P32E2):
+    return _chain_out(_soft_sqrt(_soft_from_bits(a)), fmt)
+
+
+def chain_neg(x):
+    return _i64(x) ^ _SIGN_BITS
+
+
+def chain_abs(x):
+    return _i64(x) & _MASK63
+
+
+def chain_isnan(x):
+    return (_i64(x) & _MASK63) > _INF_BITS
+
+
+def chain_iszero(x):
+    return (_i64(x) & _MASK63) == 0
+
+
+def chain_positive(x):
+    """x > 0 (False for NaN), as the f64 comparison would say."""
+    x = _i64(x)
+    return (x > 0) & (x <= _INF_BITS)
+
+
+def chain_const(x: float):
+    """A Python scalar as a chain value (it must lie on the lattice)."""
+    return _i64(int(np.asarray(x, np.float64).view(np.int64)))
 
 
 def chain_round(x, fmt: PositFormat = P32E2):
-    """Round an f64 value to the nearest posit *value* (RNE on the pattern
-    boundary, saturating, NaN -> NaN), staying in f64.  Bit-equivalent to
-    ``to_float64(from_float64(x))`` (pinned by tests), but computed
-    directly on (scale, significand) fields — no pattern pack/unpack, so
-    a chain step costs roughly half an encode+decode round-trip.
+    """The f64 reference of the chain rounding: round an f64 value to the
+    nearest posit *value* (RNE on the pattern boundary, saturating,
+    NaN -> NaN), staying in f64.  Bit-equivalent to
+    ``to_float64(from_float64(x))`` (pinned by tests), computed directly
+    on (scale, significand) fields; ``_soft_posit_round`` is its integer
+    twin on the device path.
 
     The rounding position is the posit pattern boundary: with
     ``reg_len``-bit regime the pattern keeps ``fs = nbits-1-reg_len-es``
@@ -500,25 +796,6 @@ def chain_round(x, fmt: PositFormat = P32E2):
     out = jnp.where(is_zero, 0.0, out)
     return jnp.where(is_nan, jnp.float64(jnp.nan), out)
 
-
-def chain_add(a, b, fmt: PositFormat = P32E2):
-    return chain_round(a + b, fmt)
-
-
-def chain_sub(a, b, fmt: PositFormat = P32E2):
-    return chain_round(a - b, fmt)
-
-
-def chain_mul(a, b, fmt: PositFormat = P32E2):
-    return chain_round(a * b, fmt)
-
-
-def chain_div(a, b, fmt: PositFormat = P32E2):
-    return chain_round(a / b, fmt)
-
-
-def chain_sqrt(a, fmt: PositFormat = P32E2):
-    return chain_round(jnp.sqrt(a), fmt)
 
 
 # --------------------------------------------------------------------------

@@ -41,8 +41,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.launch.compat import axis_index  # also installs shard_map shim
-from repro.launch.mesh import make_grid_mesh
+from repro.launch.mesh import make_grid_mesh, replicated
 
 __all__ = ["BlockCyclic", "DistMatrix", "distribute", "scatter_array",
            "gather_array", "local_gidx", "unshuffle", "select_block_col",
@@ -115,7 +114,7 @@ def scatter_array(x, lay: BlockCyclic):
 def gather_array(d, lay: BlockCyclic):
     """(P*lm, Q*ln) dist array -> replicated (m, n) posit words (inverse
     of ``scatter_array``)."""
-    d = jnp.asarray(d)
+    d = jnp.asarray(replicated(d))
     t = d.reshape(lay.p, lay.lmb, lay.nb, lay.q, lay.lnb, lay.nb)
     # dist block (r, t) holds global block r + P*t: ascending global order
     # is (t outer, r inner); padding blocks land at the end of each axis.
@@ -200,4 +199,4 @@ def select_block_col(a_loc: jax.Array, lay: BlockCyclic, coord, j: int,
 
 def grid_coords():
     """Traced (row, col) coordinate of the executing device."""
-    return axis_index("row"), axis_index("col")
+    return jax.lax.axis_index("row"), jax.lax.axis_index("col")

@@ -55,12 +55,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.core.formats import P32E2, PositFormat
 from repro.core import posit
 from repro.kernels.ops import rgemm
 from repro.launch.collectives import limb_psum
-from repro.launch.compat import shard_map
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
 from repro.obs import trace as _obs_trace
@@ -141,7 +141,7 @@ def _pdgemm_local(a_loc, b_loc, c_loc, lay_a, lay_b, alpha, beta,
                              "schedule; use backend='quire_exact'")
         a_in = a_loc
         if alpha not in (1.0, -1.0, 1, -1):
-            alpha_p = posit.from_float64(jnp.float64(alpha), fmt)
+            alpha_p = posit.word_of(alpha, fmt)
             a_in = posit.mul(alpha_p, a_loc, fmt, backend="fast")
         limbs, nar = _k_slab_limbs(a_in, b_loc, lay_a, lay_b,
                                    negate=alpha in (-1.0, -1), fmt=fmt)
@@ -149,7 +149,7 @@ def _pdgemm_local(a_loc, b_loc, c_loc, lay_a, lay_b, alpha, beta,
         if beta in (1.0, 1):
             q = qadd_posit(q, c_loc, fmt)
         elif beta not in (0.0, 0):
-            beta_p = posit.from_float64(jnp.float64(beta), fmt)
+            beta_p = posit.word_of(beta, fmt)
             q = qadd_posit(q, posit.mul(beta_p, c_loc, fmt, backend="fast"),
                            fmt)
         return q_to_posit(q, fmt)

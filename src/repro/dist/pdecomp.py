@@ -46,6 +46,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.core.formats import P32E2
 from repro.kernels.ops import rgemm
@@ -53,7 +54,6 @@ from repro.lapack import solve
 from repro.lapack.blas import rtrsm_left_lower, rtrsm_right_lowerT
 from repro.lapack.decomp import getf2, potf2
 from repro.lapack.refine import refine_pair
-from repro.launch.compat import shard_map
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
 from repro.obs import trace as _obs_trace

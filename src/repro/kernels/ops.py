@@ -11,10 +11,13 @@ sending them to the FPGA").  Backends:
   (quire-lite semantics).  For alpha in {1, -1} and beta = 0 the rounding
   is fused into the kernel's final-k step (int32 posit words come
   straight off the kernel — DESIGN.md §2.1); other alpha/beta use the
-  f32-accumulator output with a host f64 epilogue.  Interpret mode on
-  CPU, compiled on TPU (auto-detected).
+  f32-accumulator output with a binary64-semantics epilogue computed in
+  integer fields (``posit.f32_epilogue``).  Interpret mode on CPU,
+  compiled on TPU (auto-detected).
 * ``xla_quire``   — decode->f64 dot->encode (same semantics, no Pallas);
-  the fast CPU path used by the decomposition benchmarks.
+  the fast CPU path used by the decomposition benchmarks.  A TPU has no
+  exact f64 dot, so on a TPU this backend raises and names the two that
+  run there.
 * ``quire_exact`` — true posit-standard quire (repro.quire): exact
   fixed-point accumulation, ONE rounding per output element.  For
   alpha in {1, -1} and beta in {0, 1} the whole update is a single fused
@@ -72,7 +75,7 @@ def _scalar_posit(x, fmt: PositFormat):
     """alpha/beta are static Python scalars -> posit words at trace time."""
     assert isinstance(x, (int, float)), (
         "alpha/beta must be static Python scalars")
-    return posit.from_float64(jnp.float64(x), fmt)
+    return posit.word_of(x, fmt)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "trans_a",
@@ -118,10 +121,7 @@ def _rgemm_jit(a_p: jax.Array, b_p: jax.Array, c_p: jax.Array | None = None,
         c0 = posit.mul(beta_p, c_p, fmt, backend="fast")
         return ref.rgemm_faithful_chain(a_p, b_scaled, c0, fmt)
 
-    if backend == "xla_quire":
-        ab = jnp.dot(posit.to_float64(a_p, fmt), posit.to_float64(b_p, fmt),
-                     precision=jax.lax.Precision.HIGHEST)
-    elif backend in ("pallas_split3", "pallas_split3_comp"):
+    if backend in ("pallas_split3", "pallas_split3_comp"):
         mode = backend.removeprefix("pallas_")
         ap = _pad_to(a_p, block, (0, 1))
         bp = _pad_to(b_p, block, (0, 1))
@@ -133,11 +133,22 @@ def _rgemm_jit(a_p: jax.Array, b_p: jax.Array, c_p: jax.Array | None = None,
             return posit_gemm(ap, bp, bm=block, bn=block, bk=block,
                               mode=mode, fmt=fmt,
                               negate=alpha in (-1.0, -1))[:m, :n]
-        ab = posit_gemm_f32(ap, bp, bm=block, bn=block, bk=block,
-                            mode=mode, fmt=fmt)[:m, :n].astype(jnp.float64)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+        acc = posit_gemm_f32(ap, bp, bm=block, bn=block, bk=block,
+                             mode=mode, fmt=fmt)[:m, :n]
+        # beta == 0: C is not referenced (BLAS convention, see below)
+        if beta in (0.0, 0):
+            return posit.f32_epilogue(acc, alpha_p, fmt=fmt)
+        return posit.f32_epilogue(acc, alpha_p, beta_p, c_p, fmt)
 
+    if backend != "xla_quire":
+        raise ValueError(f"unknown backend {backend!r}")
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            "rgemm backend 'xla_quire' accumulates in f64, which a TPU does "
+            "not compute exactly; use backend='quire_exact' (exact) or "
+            "'pallas_split3' (the TPU kernel)")
+    ab = jnp.dot(posit.to_float64(a_p, fmt), posit.to_float64(b_p, fmt),
+                 precision=jax.lax.Precision.HIGHEST)
     if beta in (0.0, 0):
         # BLAS convention: beta == 0 means C is NOT referenced (it may
         # hold garbage/NaR), matching the quire_exact and fused-pallas

@@ -54,12 +54,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.formats import P16E1, P32E2, PositFormat
 
-try:  # TPU-specific pieces; interpret mode works without a TPU backend.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NAN = np.float32(np.nan)
 
@@ -75,13 +70,13 @@ def _floor_log2_i32(x):
         t = x >> s
         big = t > 0
         x = jnp.where(big, t, x)
-        r = r + jnp.where(big, s, 0)
+        r = r + jnp.where(big, np.int32(s), np.int32(0))
     return r
 
 
 def _pow2_f32(e):
     """2.0**e as f32 via exponent-field construction; caller masks e < -126."""
-    bits = (jnp.clip(e + 127, 1, 254) << 23).astype(jnp.int32)
+    bits = jnp.clip(e + 127, np.int32(1), np.int32(254)) << 23
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
@@ -101,7 +96,7 @@ def decode_split_f32(p, fmt: PositFormat = P32E2):
     body = a << (33 - nbits)                             # regime MSB at bit31
     r0 = body < 0
     y = jnp.where(r0, ~body, body)                       # bit31 == 0 now
-    y_safe = jnp.where(y == 0, 1, y)
+    y_safe = jnp.where(y == 0, np.int32(1), y)
     m = 31 - _floor_log2_i32(y_safe)                     # regime run length
     k = jnp.where(r0, m - 1, -m)
     u = (body << m) << 1                                 # strip regime+term
@@ -112,8 +107,9 @@ def decode_split_f32(p, fmt: PositFormat = P32E2):
 
     sgn = jnp.where(signbit, jnp.float32(-1.0), jnp.float32(1.0))
     dead = is_zero | is_nar
-    ph = jnp.where((scale - 23 >= -126) & ~dead, _pow2_f32(scale - 23), 0.0)
-    plo = jnp.where((scale - 27 >= -126) & ~dead, _pow2_f32(scale - 27), 0.0)
+    zero = np.float32(0.0)
+    ph = jnp.where((scale - 23 >= -126) & ~dead, _pow2_f32(scale - 23), zero)
+    plo = jnp.where((scale - 27 >= -126) & ~dead, _pow2_f32(scale - 27), zero)
     hi = (sig >> 4).astype(jnp.float32) * ph * sgn
     lo = (sig & 15).astype(jnp.float32) * plo * sgn
     hi = jnp.where(is_nar, _NAN, hi)
@@ -149,7 +145,7 @@ def encode_posit_f32(x, fmt: PositFormat = P32E2):
     scale = jnp.where(expf == 0, jnp.int32(-150), expf - 127)
     over = scale >= ms                                   # k_max regime: maxpos
     under = (scale < -ms) & ~is_zero
-    sc = jnp.clip(scale, -ms, ms - 1)                    # shift-safe lanes
+    sc = jnp.clip(scale, np.int32(-ms), np.int32(ms - 1))                    # shift-safe lanes
 
     k = sc >> es                                         # floor(scale / 2^es)
     e = sc & ((1 << es) - 1)
@@ -158,8 +154,8 @@ def encode_posit_f32(x, fmt: PositFormat = P32E2):
     regime = jnp.where(k >= 0,
                        ((jnp.int32(1) << (k + 1)) - 1) << 1, jnp.int32(1))
     ef = (jnp.int32(1) << (es + 23)) | (e << 23) | man   # [1|e|frac23]
-    d = jnp.maximum((es + 23) - avail, 0)                # [e|frac] bits dropped
-    shl = jnp.maximum(avail - (es + 23), 0)              # or left-padded
+    d = jnp.maximum((es + 23) - avail, np.int32(0))                # [e|frac] bits dropped
+    shl = jnp.maximum(avail - (es + 23), np.int32(0))              # or left-padded
     kf = (ef >> d) - (jnp.int32(1) << ((es + 23) - d))   # strip hidden bit
     pat0 = (regime << avail) | (kf << shl)
     dropped = ef & ((jnp.int32(1) << d) - 1)
@@ -171,7 +167,7 @@ def encode_posit_f32(x, fmt: PositFormat = P32E2):
     pat = jnp.where(over, jnp.int32(fmt.maxpos_pattern), pat)  # never NaR
     pat = jnp.where(under, jnp.int32(1), pat)            # clamp at minpos
     out = jnp.where(sign, jnp.int32(0) - pat, pat)       # 2's-complement neg
-    out = jnp.where(is_zero, 0, out)
+    out = jnp.where(is_zero, np.int32(0), out)
     return jnp.where(is_nar, np.int32(fmt.nar_pattern), out)
 
 
@@ -193,8 +189,11 @@ def encode_p16_f32(x):
 # --------------------------------------------------------------------------
 
 def _matmul_f32(x, y):
+    # The split is exact only if the MXU multiplies the 24-bit planes at
+    # full f32 precision; HIGHEST pins that (Mosaic's fp32 contraction).
     return jax.lax.dot_general(
         x, y, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -236,15 +235,6 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, err_ref, *, n_k, compensated,
 # pallas_call wrappers
 # --------------------------------------------------------------------------
 
-def _resolve_interpret(interpret):
-    """Satellite fix: ``interpret=None`` auto-detects — compile the kernel
-    on a real TPU backend, fall back to interpret mode elsewhere (CPU/GPU
-    validation), so callers never thread the flag."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 def _posit_gemm_call(a_p, b_p, *, bm, bn, bk, mode, interpret, emit_posit,
                      negate, fmt):
     m, k = a_p.shape
@@ -252,35 +242,38 @@ def _posit_gemm_call(a_p, b_p, *, bm, bn, bk, mode, interpret, emit_posit,
     assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0, (
         (m, k, n), (bm, bn, bk))
     compensated = {"split3": False, "split3_comp": True}[mode]
-    interpret = _resolve_interpret(interpret)
     n_k = k // bk
-
-    grid = (m // bm, n // bn, n_k)
     kernel = functools.partial(_kernel, n_k=n_k, compensated=compensated,
                                emit_posit=emit_posit, negate=negate, fmt=fmt)
-    scratch = [_VMEM((bm, bn), jnp.float32), _VMEM((bm, bn), jnp.float32)]
     out_dtype = jnp.int32 if emit_posit else jnp.float32
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    def call(a, b, interpret):
+        kwargs = {} if interpret else {
+            "compiler_params": pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"))}
+        return pl.pallas_call(
+            kernel,
+            grid=(m // bm, n // bn, n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                            pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=interpret,
+            **kwargs,
+        )(a, b)
 
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **kwargs,
-    )(a_p, b_p)
+    if interpret is not None:
+        return call(a_p, b_p, interpret)
+    # Compiled for a TPU, the Pallas interpreter on any other platform:
+    # chosen when the program is lowered for its platform, so a program
+    # lowered for a TPU always holds the compiled kernel.
+    return jax.lax.platform_dependent(
+        a_p, b_p, tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "mode",
@@ -292,8 +285,8 @@ def posit_gemm_f32(a_p: jax.Array, b_p: jax.Array, *, bm: int = 128,
     """(M,K) @ (K,N) over int32 posit words -> f32 accumulator.
 
     M, N, K must be multiples of the (MXU-aligned) block sizes; ops.py pads.
-    ``interpret=None`` auto-detects (compiled on TPU, Python interpreter
-    elsewhere); pass True/False to force.  ``fmt`` selects the posit
+    ``interpret=None`` compiles the kernel in a program lowered for a TPU
+    and interprets it on any other platform; pass True/False to force.  ``fmt`` selects the posit
     format of the input words (static; constants fold at trace).
     """
     return _posit_gemm_call(a_p, b_p, bm=bm, bn=bn, bk=bk, mode=mode,

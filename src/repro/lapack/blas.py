@@ -35,7 +35,7 @@ def rtrsm_left_lower(l_p: jax.Array, b_p: jax.Array, unit_diag: bool = True,
 
     Forward substitution in rank-1-update order: n steps, each a
     vectorized posit mul+sub over the remaining rows.  Fused-chain
-    execution (core/posit.py): L and B decode to f64 once, each scalar op
+    execution (core/posit.py): L and B decode once, each scalar op
     is still individually posit-rounded, words are packed once at exit —
     bit-identical to per-op fast-backend words.
     """
@@ -164,7 +164,7 @@ def rlarfg_chain(col: jax.Array, k, fmt: PositFormat = P32E2):
     """Generate the Householder reflector H = I - tau v v^T annihilating
     ``col`` below index ``k`` (dlarfg, every scalar op posit-rounded).
 
-    ``col`` is a fused-chain (decoded f64) column; ``k`` the pivot index
+    ``col`` is a fused-chain (decoded) column; ``k`` the pivot index
     (traced).  Returns chain-domain ``(newcol, v, tau)``:
 
     * ``newcol`` — beta = -sign(alpha) * ||col[k:]|| at index k (no
@@ -182,21 +182,22 @@ def rlarfg_chain(col: jax.Array, k, fmt: PositFormat = P32E2):
         upd = posit.chain_add(s, posit.chain_mul(col[i], col[i], fmt), fmt)
         return jnp.where(i > k, upd, s), None
 
-    s2, _ = jax.lax.scan(acc, jnp.float64(0.0), rows)
+    s2, _ = jax.lax.scan(acc, posit.chain_const(0.0), rows)
     alpha = col[k]
     norm = posit.chain_sqrt(
         posit.chain_add(posit.chain_mul(alpha, alpha, fmt), s2, fmt), fmt)
     # posit rounding saturates at minpos (never flushes to zero), so
     # s2 == 0 iff every tail element is exactly zero
-    trivial = s2 == 0.0
-    beta = jnp.where(alpha > 0, -norm, norm)
-    tau = jnp.where(trivial, 0.0,
+    trivial = posit.chain_iszero(s2)
+    beta = jnp.where(posit.chain_positive(alpha), posit.chain_neg(norm), norm)
+    tau = jnp.where(trivial, posit.chain_const(0.0),
                     posit.chain_div(posit.chain_sub(beta, alpha, fmt), beta,
                                     fmt))
     denom = posit.chain_sub(alpha, beta, fmt)
     tail = posit.chain_div(col, denom, fmt)
-    v = jnp.where(rows == k, 1.0,
-                  jnp.where((rows > k) & ~trivial, tail, 0.0))
+    v = jnp.where(rows == k, posit.chain_const(1.0),
+                  jnp.where((rows > k) & ~trivial, tail,
+                            posit.chain_const(0.0)))
     newcol = jnp.where(
         rows == k, jnp.where(trivial, alpha, beta),
         jnp.where(rows > k, jnp.where(trivial, col, tail), col))

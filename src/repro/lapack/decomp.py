@@ -29,10 +29,11 @@ ensemble protocol (many matrices x many phi scales) as one batched
 program.
 
 Panel kernels run in fused-chain form (core/posit.py): operands decode to
-f64 once at panel entry, every scalar op is still individually rounded to
-the posit lattice (``chain_round``), and words are encoded once at panel
-exit — bit-identical to per-op fast-backend words, minus the redundant
-decode/encode round-trips.
+binary64 patterns once at panel entry, every scalar op is still
+individually rounded to the posit lattice, and words are encoded once at
+panel exit — bit-identical to per-op fast-backend words, minus the
+redundant decode/encode round-trips.  Integer ops only, so the panels
+compile for a TPU.
 
 binary32 baselines (spotrf/sgetrf) use the same XLA algorithms in f32,
 standing in for LAPACK's spotrf/sgetrf as in the paper's comparison.
@@ -63,8 +64,9 @@ _FMT = P32E2
 def potf2(a_p: jax.Array, fmt: PositFormat = P32E2) -> jax.Array:
     """Unblocked lower Cholesky of an (n,n) posit matrix, dpotf2 op order.
 
-    Decode-once / encode-once: the panel enters f64 once, every scalar op
-    is posit-rounded in place (chain_round), words are packed once at exit.
+    Decode-once / encode-once: the panel enters the chain domain once,
+    every scalar op is posit-rounded in place, words are packed once at
+    exit.
     """
     n = a_p.shape[0]
     rows = jnp.arange(n)
@@ -93,17 +95,18 @@ def getf2(a_p: jax.Array, nb: int, fmt: PositFormat = P32E2):
 
     Returns (panel, ipiv) with L strictly-below-diagonal (unit diag) and U
     on/above.  Pivot search compares |value| — decoded posit values order
-    exactly like the word patterns (posits are monotone), so the f64
-    comparison picks the same pivot the word comparison did.  Fused-chain
-    execution: decode once, per-op rounding in f64, encode once.
+    exactly like the word patterns (posits are monotone), and so do the
+    int64 patterns of nonnegative chain values, so the pattern comparison
+    picks the same pivot the word comparison did.  Fused-chain execution:
+    decode once, per-op rounding in fields, encode once.
     """
     m = a_p.shape[0]
     rows = jnp.arange(m)
     a0 = posit.chain_decode(a_p, fmt)
 
     def step(a, k):
-        col = jnp.where(rows >= k, jnp.abs(a[:, k]), -1.0)
-        col = jnp.where(jnp.isnan(col), -1.0, col)       # NaR never pivots
+        col = jnp.where(rows >= k, posit.chain_abs(a[:, k]), -1)
+        col = jnp.where(posit.chain_isnan(a[:, k]), -1, col)  # NaR: never
         piv = jnp.argmax(col).astype(jnp.int32)
         rk, rp = a[k, :], a[piv, :]
         a = a.at[k, :].set(rp).at[piv, :].set(rk)

@@ -74,7 +74,7 @@ def geqr2(a_p: jax.Array, fmt: PositFormat = P32E2):
 
     Returns (panel, tau): R on/above the diagonal, the reflectors' tails
     below it (v_k = 1 implicit), and the (w,) tau posit words.  Fused-
-    chain execution: the panel decodes to f64 once, every scalar op is
+    chain execution: the panel decodes once, every scalar op is
     posit-rounded in place, words are packed once at exit.
     """
     m, w = a_p.shape
@@ -102,7 +102,7 @@ def geqr2(a_p: jax.Array, fmt: PositFormat = P32E2):
         a = a.at[:, k].set(newcol)
         return (a, taus.at[k].set(tau)), None
 
-    (a, taus), _ = jax.lax.scan(step, (a0, jnp.zeros((w,), jnp.float64)),
+    (a, taus), _ = jax.lax.scan(step, (a0, jnp.zeros((w,), jnp.int64)),
                                 cols)
     return posit.chain_encode(a, fmt), posit.chain_encode(taus, fmt)
 
@@ -128,21 +128,21 @@ def larft(v_p: jax.Array, tau_p: jax.Array,
                                                v[r, :][None, :], fmt), fmt)
         return g, None
 
-    g, _ = jax.lax.scan(accg, jnp.zeros((w, w)), jnp.arange(m))
+    g, _ = jax.lax.scan(accg, jnp.zeros((w, w), jnp.int64), jnp.arange(m))
 
     def tcol(t, j):
-        h = posit.chain_mul(jnp.negative(tau[j]), g[:, j], fmt)
+        h = posit.chain_mul(posit.chain_neg(tau[j]), g[:, j], fmt)
 
         def acct(s, el):
             upd = posit.chain_add(s, posit.chain_mul(t[:, el], h[el], fmt),
                                   fmt)
             return jnp.where(el < j, upd, s), None
 
-        h2, _ = jax.lax.scan(acct, jnp.zeros((w,)), cols)
-        newcol = jnp.where(cols < j, h2, jnp.where(cols == j, tau[j], 0.0))
+        h2, _ = jax.lax.scan(acct, jnp.zeros((w,), jnp.int64), cols)
+        newcol = jnp.where(cols < j, h2, jnp.where(cols == j, tau[j], 0))
         return t.at[:, j].set(newcol), None
 
-    t, _ = jax.lax.scan(tcol, jnp.zeros((w, w)), cols)
+    t, _ = jax.lax.scan(tcol, jnp.zeros((w, w), jnp.int64), cols)
     return posit.chain_encode(t, fmt)
 
 
@@ -152,7 +152,7 @@ def _v_words(panel_p: jax.Array, fmt: PositFormat) -> jax.Array:
     mj, w = panel_p.shape
     rows = jnp.arange(mj)[:, None]
     cols = jnp.arange(w)[None, :]
-    one = posit.from_float64(jnp.float64(1.0), fmt)
+    one = posit.word_of(1.0, fmt)
     return jnp.where(rows > cols, panel_p,
                      jnp.where(rows == cols, one, 0))
 

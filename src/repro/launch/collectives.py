@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.launch.compat import axis_size  # also installs jax.shard_map shim
 from repro.core.policy import decode_tensor, encode_tensor
 
 _GRAD_SCALE = 2.0 ** 8     # golden-zone re-centering for layer-norm'd grads
@@ -29,7 +28,7 @@ def compressed_psum(x: jax.Array, axis_name: str,
     all-gather phase: encoded own-chunk broadcast.  Mathematically the
     standard two-phase all-reduce; wire dtype int16.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     orig_shape = x.shape
     orig_dtype = x.dtype
     flat = x.reshape(-1).astype(jnp.float32)

@@ -25,10 +25,26 @@ def make_grid_mesh(p: int = 1, q: int = 1):
     algebra subsystem (repro.dist) — ScaLAPACK's 2D grid in mesh form.
     Runs on any device set: TPU slices, or CPU host devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the hermetic
-    tier-1 path)."""
-    return jax.make_mesh((p, q), ("row", "col"))
+    tier-1 path).  Both axes are Auto: the layer partitions with
+    NamedSharding and shard_map, never by sharding-in-types."""
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((p, q), ("row", "col"), axis_types=(auto, auto))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
     names = mesh.axis_names
     return tuple(a for a in ("pod", "data") if a in names)
+
+
+def replicated(x):
+    """A concrete array sharded over a mesh, as one replicated on that
+    mesh; anything else unchanged.  Arrays on an Explicit-axis mesh (the
+    default of ``jax.make_mesh`` since JAX 0.9) refuse reshapes that split
+    or merge their sharded dimensions, which gathering a distributed
+    matrix or flattening it for statistics needs."""
+    sharding = getattr(x, "sharding", None)
+    if isinstance(x, jax.core.Tracer) or not isinstance(
+            sharding, jax.sharding.NamedSharding):
+        return x
+    return jax.device_put(x, jax.sharding.NamedSharding(
+        sharding.mesh, jax.sharding.PartitionSpec()))

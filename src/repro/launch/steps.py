@@ -10,11 +10,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import context as dist_ctx
 from repro.launch.collectives import compressed_psum_tree
-from repro.launch.compat import axis_size, shard_map
 from repro.launch.mesh import dp_axes
 from repro.models.common import ArchConfig
 from repro.models.lm import forward_prefill, forward_train, serve_step
@@ -74,7 +74,7 @@ def make_train_step_compressed(cfg: ArchConfig, mesh, *, remat: bool = True,
             grads = compressed_psum_tree(grads, ax)
         dp_size = 1
         for ax in dp:
-            dp_size *= axis_size(ax)
+            dp_size *= jax.lax.axis_size(ax)
         grads = jax.tree.map(lambda g: g / dp_size, grads)
         params2, opt2, gnorm = adamw_update(
             params, opt_state, grads, lr=lr,

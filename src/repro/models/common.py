@@ -222,7 +222,7 @@ def embed(params, ids, compute_dtype):
     psum, which needs manual sharding — done here with shard_map over the
     'model' axis when a distribution context is active."""
     from repro.launch import context as dist_ctx
-    from repro.launch.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     table = leaf(params["table"])
     ctx = dist_ctx.current()

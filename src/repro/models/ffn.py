@@ -33,64 +33,6 @@ def ffn_apply(params, x, cfg: ArchConfig, policy, compute_dtype):
     return linear(params["w_down"], h, policy, compute_dtype)
 
 
-# --------------------------------------------------------------------------
-# grouped GEMM with a dtype-correct VJP
-#
-# jax 0.4.x's ragged_dot transpose emits its cotangent in
-# preferred_element_type (f32) instead of the primal operand dtype; when
-# the SAME bf16 activation feeds two ragged_dots inside a scanned layer
-# stack, the scan transpose adds a bf16 and an f32 cotangent for one
-# variable and trips `assert core.typematch` (the MoE-smoke AssertionError
-# at steps.py:47).  This custom_vjp computes both gradients explicitly —
-# dx as a ragged_dot against w^T, dw as a per-group masked einsum — and
-# casts each cotangent back to its primal dtype.
-# --------------------------------------------------------------------------
-
-@jax.custom_vjp
-def _grouped_mm(x, w, group_sizes):
-    """ragged_dot with f32 accumulation: (T, d) @ (E, d, f) -> (T, f),
-    rows of x grouped by expert via ``group_sizes`` (sums to T)."""
-    return jax.lax.ragged_dot(x, w, group_sizes,
-                              preferred_element_type=jnp.float32)
-
-
-def _grouped_mm_fwd(x, w, group_sizes):
-    return _grouped_mm(x, w, group_sizes), (x, w, group_sizes)
-
-
-def _grouped_mm_bwd(res, dy):
-    x, w, group_sizes = res
-    e, d, f = w.shape
-    dy32 = dy.astype(jnp.float32)
-    dx = jax.lax.ragged_dot(dy32, jnp.swapaxes(w, 1, 2).astype(jnp.float32),
-                            group_sizes,
-                            preferred_element_type=jnp.float32)
-    # dw[e] = x_g^T @ dy_g per group: segment-summed outer products at
-    # forward-matmul FLOP cost, chunked over rows so the transient
-    # (chunk, d, f) outer never materializes at full T
-    t = x.shape[0]
-    gid = jnp.repeat(jnp.arange(e), group_sizes, total_repeat_length=t)
-    chunk = min(t, 128)
-    pad = (-t) % chunk
-    xb = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0))
-                 ).reshape(-1, chunk, d)
-    dyb = jnp.pad(dy32, ((0, pad), (0, 0))).reshape(-1, chunk, f)
-    gb = jnp.pad(gid, (0, pad)).reshape(-1, chunk)       # pad rows are 0s
-
-    def blk(dw, args):
-        xc, dc, gc = args
-        outer = xc[:, :, None] * dc[:, None, :]          # (chunk, d, f)
-        return dw + jax.ops.segment_sum(outer, gc, num_segments=e), None
-
-    dw, _ = jax.lax.scan(blk, jnp.zeros((e, d, f), jnp.float32),
-                         (xb, dyb, gb))
-    gs_ct = jnp.zeros(group_sizes.shape, jax.dtypes.float0)
-    return dx.astype(x.dtype), dw.astype(w.dtype), gs_ct
-
-
-_grouped_mm.defvjp(_grouped_mm_fwd, _grouped_mm_bwd)
-
-
 def moe_init(key, cfg: ArchConfig):
     ks = jax.random.split(key, 4)
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
@@ -140,7 +82,8 @@ def moe_apply_local(params, x, cfg: ArchConfig, policy, compute_dtype):
 
     def grouped(w):
         ww = policy.maybe_quantize_weights(leaf(w)).astype(compute_dtype)
-        return lambda inp: _grouped_mm(inp, ww, group_sizes)
+        return lambda inp: jax.lax.ragged_dot(
+            inp, ww, group_sizes, preferred_element_type=jnp.float32)
 
     gate = grouped(params["w_gate"])(xs)
     up = grouped(params["w_up"])(xs)
@@ -171,7 +114,7 @@ def moe_apply_ep(params, x, cfg: ArchConfig, policy, compute_dtype, ctx,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.compat import shard_map
+    from jax import shard_map
 
     mesh, dp, ep = ctx.mesh, ctx.dp, ctx.ep
     e, k = cfg.n_experts, cfg.top_k

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import posit
 from repro.core.formats import P32E2, PositFormat
+from repro.launch.mesh import replicated
 from repro.obs import metrics as _metrics
 
 _I64 = jnp.int64
@@ -193,7 +194,7 @@ def record_numerics(name: str, words, fmt: PositFormat = P32E2):
     stats dict (or None on the disabled path)."""
     if not active(words):
         return None
-    st = collect_numerics(words, fmt)
+    st = collect_numerics(replicated(words), fmt)
     _metrics.gauge(f"{name}.golden_zone", st["golden_frac"])
     _metrics.gauge(f"{name}.regime_mean", st["regime_mean"])
     _metrics.inc(f"{name}.words", st["n"])

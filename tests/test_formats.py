@@ -66,15 +66,23 @@ def _h(*arrs):
     return m.hexdigest()[:16]
 
 
-@pytest.fixture(scope="module")
-def golden_inputs():
+def golden_words():
+    """The pins' inputs (a general matrix A, the SPD matrix A^T A and a
+    right-hand side b, as p32e2 words), converted with integer ops only so
+    a TPU makes the same words: chip_smoke.py reproduces the pins from
+    this recipe and the hashes above."""
     rng = np.random.default_rng(42)
     a64 = rng.standard_normal((48, 48))
     s64 = a64.T @ a64
     b64 = rng.standard_normal(48)
-    return (P.from_float64(jnp.asarray(a64)),
-            P.from_float64(jnp.asarray(s64)),
-            P.from_float64(jnp.asarray(b64)))
+    import jax
+    to_words = jax.jit(P.from_float64_bits)
+    return tuple(to_words(x.view(np.int64)) for x in (a64, s64, b64))
+
+
+@pytest.fixture(scope="module")
+def golden_inputs():
+    return golden_words()
 
 
 def test_golden_rgemm_backends(golden_inputs):

@@ -167,11 +167,12 @@ def test_full_configs_match_spec():
 
 
 def test_grouped_mm_gradients_match_dense_reference():
-    """The MoE grouped GEMM's custom VJP (dtype-correct cotangents; fixes
-    the scan-transpose AssertionError in the MoE train step) must agree
-    with a dense per-row reference on both operand gradients."""
+    """The MoE grouped GEMM (``jax.lax.ragged_dot`` with f32 accumulation,
+    as ``moe_apply_local`` calls it) must agree with a dense per-row
+    reference on both operand gradients, and a bf16 activation feeding
+    two grouped GEMMs inside a scanned layer stack (the MoE train step's
+    shape) must differentiate with primal-dtype cotangents."""
     import jax.numpy as jnp
-    from repro.models.ffn import _grouped_mm
 
     rng = np.random.default_rng(0)
     t, d, f, e = 12, 5, 7, 3
@@ -180,13 +181,31 @@ def test_grouped_mm_gradients_match_dense_reference():
     w = jnp.asarray(rng.standard_normal((e, d, f)), jnp.float32)
     gid = np.repeat(np.arange(e), np.asarray(gs))
 
+    def grouped(x, w):
+        return jax.lax.ragged_dot(x, w, gs,
+                                  preferred_element_type=jnp.float32)
+
     def ref(x, w):
         return sum((x[i] @ w[gid[i]]).sum() for i in range(t))
 
     def ours(x, w):
-        return _grouped_mm(x, w, gs).sum()
+        return grouped(x, w).sum()
 
     gx1, gw1 = jax.grad(ref, argnums=(0, 1))(x, w)
     gx2, gw2 = jax.grad(ours, argnums=(0, 1))(x, w)
     np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx2), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(gw1), np.asarray(gw2), rtol=1e-5)
+
+    ws = jnp.asarray(rng.standard_normal((2, 2, e, d, d)), jnp.bfloat16)
+
+    def stack(ws, h):
+        def layer(h, wl):
+            a, b = grouped(h, wl[0]), grouped(h, wl[1])
+            return (jax.nn.silu(a) * b).astype(jnp.bfloat16), None
+        h, _ = jax.lax.scan(layer, h, ws)
+        return h.astype(jnp.float32).sum()
+
+    gw, gh = jax.grad(stack, argnums=(0, 1))(ws, x.astype(jnp.bfloat16)[
+        :, :d])
+    assert gw.dtype == jnp.bfloat16 and gh.dtype == jnp.bfloat16
+    assert np.isfinite(np.asarray(gw, np.float32)).all()

@@ -9,6 +9,9 @@ collects and tests.
 import numpy as np
 from fractions import Fraction
 
+import jax.numpy as jnp
+import pytest
+
 try:
     from hypothesis import given, settings, strategies as st
     HAVE_HYPOTHESIS = True
@@ -339,3 +342,93 @@ def test_golden_zone_eps():
     xs_out = np.array([1e6, 1e-6, 1e12])
     eps_out = np.asarray(P.rounding_eps(xs_out))
     assert (eps_out > 6e-8).all()
+
+
+# --------------------------------------------------------------------------
+# binary64 in integer fields (the device path) vs real f64 arithmetic
+# --------------------------------------------------------------------------
+
+_NAN64 = 0x7FF8000000000000
+
+
+def _f64_bits(x):
+    """f64 values -> int64 patterns, every NaN as the canonical one."""
+    x = np.asarray(x, np.float64)
+    return np.where(np.isnan(x), _NAN64, x.view(np.int64))
+
+
+def _operand_words(fmt, rng, n=60000):
+    """Random words over the whole pattern space, golden-zone values with
+    full fractions, and every pairing of the specials."""
+    half = 1 << (fmt.nbits - 1)
+    w = rng.integers(-half, half, (2, n))
+    g = np.asarray(P.from_float64(
+        rng.standard_normal((2, n)) * np.exp2(rng.integers(-3, 4, (2, n))),
+        fmt))
+    sp = np.array([0, 1, -1, fmt.nar_pattern, fmt.maxpos_pattern,
+                   -fmt.maxpos_pattern, 1 << (fmt.nbits - 2)])
+    a = np.concatenate([w[0], g[0], np.repeat(sp, sp.size)])
+    b = np.concatenate([w[1], g[1], np.tile(sp, sp.size)])
+    return a.astype(np.int32), b.astype(np.int32)
+
+
+@pytest.mark.parametrize("fmt", [P32E2, P16E1, P8E2], ids=lambda f: f.name)
+def test_integer_f64_ops_match_f64(fmt):
+    """The fast backend's words and the fused-chain values, computed in
+    integer fields, equal what real f64 arithmetic + the f64 reference
+    rounding give, for + - * / sqrt."""
+    a, b = _operand_words(fmt, np.random.default_rng(21))
+    fa, fb = np.asarray(P.to_float64(a, fmt)), np.asarray(P.to_float64(b, fmt))
+    ca, cb = P.chain_decode(a, fmt), P.chain_decode(b, fmt)
+    with np.errstate(all="ignore"):
+        ref = {"add": fa + fb, "sub": fa - fb, "mul": fa * fb,
+               "div": fa / fb, "sqrt": np.sqrt(fa)}
+    for name, r in ref.items():
+        args = (a,) if name == "sqrt" else (a, b)
+        chain_args = (ca,) if name == "sqrt" else (ca, cb)
+        words = np.asarray(P._FAST[name](*args, fmt))
+        assert np.array_equal(words, np.asarray(P.from_float64(r, fmt))), (
+            fmt.name, name)
+        chain = np.asarray(getattr(P, "chain_" + name)(*chain_args, fmt))
+        want = _f64_bits(P.chain_round(r, fmt))
+        assert np.array_equal(chain, want), (fmt.name, name)
+
+
+def test_integer_f64_conversions_match_f64():
+    """to_float64_bits / from_float64_bits / pconvert / f32_epilogue are
+    bit-identical to their f64 references (XLA reads f64 and f32
+    subnormals as zero; so do they)."""
+    rng = np.random.default_rng(22)
+    x = rng.standard_normal(50000) * np.exp2(rng.uniform(-150, 150, 50000))
+    x = np.concatenate([x, [0.0, -0.0, np.inf, -np.inf, np.nan, 5e-324,
+                            -1e-310, 2.0 ** 120, 2.0 ** -120, 2.0 ** 121]])
+    for fmt in (P32E2, P16E1, P8E2):
+        got = np.asarray(P.from_float64_bits(x.view(np.int64), fmt))
+        words = np.asarray(P.from_float64(x, fmt))
+        assert np.array_equal(got, words), fmt.name
+        bits = np.asarray(P.to_float64_bits(words, fmt))
+        assert np.array_equal(bits, _f64_bits(P.to_float64(words, fmt)))
+        assert int(P.word_of(-1.5, fmt)) == int(P.from_float64(-1.5, fmt))
+    p16 = np.asarray(P.from_float64(x, P16E1))
+    assert np.array_equal(
+        np.asarray(P.pconvert(p16, P16E1, P32E2)),
+        np.asarray(P.from_float64(P.to_float64(p16, P16E1), P32E2)))
+    assert np.array_equal(
+        np.asarray(P.pconvert(words, P8E2, P16E1)),
+        np.asarray(P.from_float64(P.to_float64(words, P8E2), P16E1)))
+
+    with np.errstate(over="ignore"):              # some overflow to inf
+        acc = (rng.standard_normal(20000) * np.exp2(
+            rng.uniform(-140, 140, 20000))).astype(np.float32)
+    acc = np.concatenate([acc, np.array([0, 1e-40, np.inf, np.nan],
+                                        np.float32)])
+    al, be, c = (np.asarray(P.from_float64(v)) for v in (
+        -0.75, 3.0, rng.standard_normal(acc.size) * 1e3))
+    acc64 = jnp.asarray(acc).astype(jnp.float64)     # XLA's f32 -> f64
+    want0 = P.from_float64(P.to_float64(al) * acc64)
+    want1 = P.from_float64(P.to_float64(al) * acc64
+                           + P.to_float64(be) * P.to_float64(c))
+    assert np.array_equal(np.asarray(P.f32_epilogue(acc, al)),
+                          np.asarray(want0))
+    assert np.array_equal(np.asarray(P.f32_epilogue(acc, al, be, c)),
+                          np.asarray(want1))

@@ -126,3 +126,14 @@ def test_quire_vs_faithful_accuracy():
     sc = np.abs(truth) + 1e-300
     assert np.median(np.abs(q - truth) / sc) <= \
         np.median(np.abs(f - truth) / sc)
+
+
+def test_xla_quire_refuses_tpu(monkeypatch):
+    """A TPU has no exact f64 dot: on one, the xla_quire backend raises
+    and names the backends that run there instead of compiling an f64
+    emulation of unknown exactness."""
+    import jax
+    ap = P.from_float64(jnp.asarray(np.eye(8) * 1.5))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="quire_exact.*pallas_split3"):
+        rgemm(ap, ap, backend="xla_quire", alpha=0.5)
