@@ -14,7 +14,8 @@ there, and the test checks the same pins on a CPU.  The pinned
 factorizations and solves ran on the f64-accumulating ``xla_quire``
 backend, which a TPU cannot run exactly; ``quire_exact`` gives the same
 words and runs here instead.  The ``pallas_split3`` pin is also the check
-that the kernel's f32 dots run at full precision on the MXU.
+that the compiled kernel's f32 dots round as the CPU's do (sequential FMA
+chains in k order, formed on the VPU; kernels/posit_gemm.py).
 
 Phase 2 (full size) factors and solves the paper's section 5.1 inputs
 (error_eval.make_general / make_spd, sigma = 1, x = ones / sqrt(n),

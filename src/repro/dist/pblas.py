@@ -22,7 +22,9 @@ benchmarks/bench_dist.py before any speedup is reported):
   slab into int64 quire limb planes (``quire.quire_gemm_limbs``, the
   pre-rounding hook) for all N output columns in dist column order; the
   cross-device reduction is a ``psum_scatter`` of those integer planes
-  across "col" — each device receives exactly its own tile's limbs —
+  across "col", sent as int32 pieces (``limb_psum_scatter``; a TPU
+  cannot reduce-scatter int64) — each device receives exactly its own
+  tile's limbs —
   and the single posit rounding happens after it.  Bit-identical to
   single-device ``quire_gemm`` *by construction* (integer limb adds are
   associative; no float partial-sum scheme can say this).  This is the
@@ -60,7 +62,7 @@ from jax import shard_map
 from repro.core.formats import P32E2, PositFormat
 from repro.core import posit
 from repro.kernels.ops import rgemm
-from repro.launch.collectives import limb_psum
+from repro.launch.collectives import limb_psum, limb_psum_scatter
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
 from repro.obs import trace as _obs_trace
@@ -126,11 +128,7 @@ def _k_slab_limbs(a_loc, b_loc, lay_a: BlockCyclic, lay_b: BlockCyclic,
     b_dist = jax.lax.all_to_all(b_slabs, "col", split_axis=0, concat_axis=1,
                                 tiled=True)
     limbs, nar = quire_gemm_limbs(a_loc, b_dist, fmt, negate=negate)
-    limbs = jax.lax.psum_scatter(limbs, "col", scatter_dimension=1,
-                                 tiled=True)              # (lm, ln, L)
-    nar = jax.lax.psum_scatter(nar.astype(jnp.int32), "col",
-                               scatter_dimension=1, tiled=True) > 0
-    return limbs, nar
+    return limb_psum_scatter(limbs, nar, "col", 1)        # (lm, ln, L)
 
 
 def _pdgemm_local(a_loc, b_loc, c_loc, lay_a, lay_b, alpha, beta,
@@ -184,7 +182,8 @@ def pdgemm_collective_plan(lay_a: BlockCyclic, lay_b: BlockCyclic,
     owner-computes: A row strip gathered along "col" ((Q, lk, lm) i32)
     + B column strip along "row" ((P, lk, ln) i32).  k_split: B strip
     gather, the (lk, Q*ln) i32 slab-exchange all_to_all, and the
-    (lm, ln, L) i64 + (lm, ln) i32 limb-plane reduce-scatter pair.
+    limb-plane reduce-scatter pair: (lm, ln, 3L) i32 wire pieces of the
+    i64 limbs (``limb_psum_scatter``) + (lm, ln) i32 NaR flags.
     """
     if not k_split:
         return {"all-gather": 4 * (lay_a.q * lay_a.ln * lay_a.lm
@@ -195,7 +194,7 @@ def pdgemm_collective_plan(lay_a: BlockCyclic, lay_b: BlockCyclic,
     return {
         "all-gather": 4 * lay_b.p * lay_b.lm * lay_b.ln,
         "all-to-all": 4 * lay_a.ln * lay_a.q * lay_b.ln,
-        "reduce-scatter": lay_c.lm * lay_c.ln * (8 * L + 4),
+        "reduce-scatter": lay_c.lm * lay_c.ln * (12 * L + 4),
     }
 
 

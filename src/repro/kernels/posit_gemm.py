@@ -1,21 +1,29 @@
-"""Pallas TPU kernel: format-parametric posit GEMM via MXU hi/lo-split.
+"""Pallas TPU kernel: format-parametric posit GEMM via a hi/lo split.
 
 TPU adaptation of the paper's accelerators (DESIGN.md §2):
 
 * The FPGA design surrounds each systolic MAC with combinational posit
-  decode/encode.  The MXU is a systolic array too, but it consumes floats —
-  so the TPU-native dataflow is *decode once per VMEM tile -> matmul on the
-  MXU -> encode once per output tile*.
+  decode/encode.  Here the dataflow is *decode once per VMEM tile -> f32
+  dot -> encode once per output tile*.
 * A decoded Posit(32,2) significand has 28 bits; float32 carries 24.  We
   split each decoded value exactly as ``x = hi + lo`` (hi: top 24 bits,
-  lo: bottom 4 bits) and compute ``A@B = Ah@Bh + (Ah@Bl + Al@Bh)`` in three
-  MXU passes with f32 accumulation — the same splitting the paper discusses
+  lo: bottom 4 bits) and compute ``A@B = Ah@Bh + (Ah@Bl + Al@Bh)`` as three
+  f32 dots — the same splitting the paper discusses
   for tensor cores (Ootomo & Yokota [28], cited in §6.3), adapted to posit
   decode.  The ``Al@Bl`` term is < 2^-48 relative and is dropped.
 * ``mode="split3_comp"`` adds tile-level Knuth TwoSum compensation of the
   K-loop accumulation (error ~ one f32 rounding per *tile* instead of per
-  K step), at ~6 VPU flops per output element per K tile — noise next to
-  the 3 MXU passes.
+  K step), at ~6 VPU flops per output element per K tile.
+
+**Which f32 dot.**  The words are fixed by how each plane product of a K
+tile rounds: as the sequential fused multiply-add chain in k order, one
+rounding per k — what XLA's CPU dot computes, and what the golden pins
+(tests/test_formats.py) hold.  A TPU's MXU accumulates the same dot in
+another order and precision, so its words differ.  The compiled kernel
+therefore forms each chain on the VPU (``_chain_split3``: f32 FMA from
+Dekker products, TwoSum and a round-to-odd sticky bit, ``_fma_f32``),
+which gives the CPU's words on a TPU; the interpreter uses XLA's dot
+(``_matmul_f32``).  The tests pin the two to each other bit for bit.
 
 ``posit_gemm`` fuses the single posit rounding (quire-lite semantics, see
 kernels/ref.py) into the final-k grid step: the last ``@pl.when`` block
@@ -30,6 +38,8 @@ Exactness domain: the hi/lo split is exact for |x| >= 2^-99 (lo's exponent
 reaches f32's normal floor at scale-27 = -126); below that lo flushes to 0
 — matching TPU subnormal-flush semantics — with relative error < 2^-24,
 far outside the paper's golden zone and below binary32's own epsilon.
+The chains match the CPU's words while products and partial sums stay in
+f32's normal range (a TPU flushes subnormals; a CPU keeps them).
 
 **Format parameterization** (DESIGN.md §8): decode and encode are one
 field-space implementation over ``PositFormat`` — every per-format number
@@ -38,8 +48,8 @@ Python constant folded at trace time, so the traced kernel for p32e2 is
 op-for-op the pre-parametric kernel (pinned by the golden tests) and
 narrower formats get the same branch-free dataflow for free.  For
 p16e1/p8e2 the decoded significand carries <= 13 bits, so the hi plane
-alone is exact and the lo-plane MXU passes multiply zeros — correct, if
-wasteful; a skip-lo fast path is future work.  ``encode_p16_f32`` /
+alone is exact: the interpreter's lo-plane dots multiply zeros, and the
+compiled kernel skips its two lo chains.  ``encode_p16_f32`` /
 ``encode_p32_f32`` are the named per-format epilogue entry points
 (bit-identical to ``posit.from_float32_bits`` per format, pinned).
 """
@@ -189,16 +199,102 @@ def encode_p16_f32(x):
 # --------------------------------------------------------------------------
 
 def _matmul_f32(x, y):
-    # The split is exact only if the MXU multiplies the 24-bit planes at
-    # full f32 precision; HIGHEST pins that (Mosaic's fp32 contraction).
+    # The interpreter's dot: on a CPU, XLA computes it as the FMA chain the
+    # compiled kernel reproduces; HIGHEST keeps any backend from dropping
+    # to bf16 passes.
     return jax.lax.dot_general(
         x, y, (((1,), (0,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
+def _i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _f32(b):
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _split12(x):
+    """x == hi + lo exactly, each with at most 12 significant bits."""
+    hi = _f32(_i32(x) & np.int32(-4096))
+    return hi, x - hi
+
+
+def _two_sum(a, b):
+    """a + b == s + e exactly (Knuth), f32 round-to-nearest."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _fma_f32(s, x, y, xs, ys):
+    """RN(s + x*y) in f32 from f32 adds and multiplies only.
+
+    ``xs``/``ys`` are ``_split12`` halves, or None for an operand with at
+    most 12 significant bits.  x*y == ph + pl exactly (Dekker), and
+    s + ph == u + e (TwoSum); rounding e + pl to odd keeps a sticky bit
+    far below u's last place, so one final round-to-nearest of u + v is
+    the correctly rounded s + x*y (double rounding is harmless after a
+    round-to-odd with two spare bits).  Exact for finite operands whose
+    products and sums stay in f32's normal range.
+    """
+    xh, xl = xs if xs is not None else (x, None)
+    yh, yl = ys if ys is not None else (y, None)
+    ph = x * y
+    pl_ = xh * yh - ph
+    if yl is not None:
+        pl_ = pl_ + xh * yl
+    if xl is not None:
+        pl_ = pl_ + xl * yh
+    if xl is not None and yl is not None:
+        pl_ = pl_ + xl * yl
+    u, e = _two_sum(s, ph)
+    v, w = _two_sum(e, pl_)
+    vb = _i32(v)
+    toward = jnp.where((vb < 0) == (w < 0), np.int32(1), np.int32(-1))
+    vb = jnp.where((w != 0) & ((vb & 1) == 0), vb + toward, vb)
+    return u + _f32(vb)
+
+
+def _chain_split3(ah, al, b_ref, fmt, lo):
+    """The three plane products of one K tile, each an f32 dot computed
+    as the sequential fused multiply-add chain in k order, combined as
+    ``hh + (hl + lh)``: the words XLA's CPU dot gives (it accumulates
+    each output with one FMA per k, in order), computed on the VPU.
+    Column t of the A planes is taken with an exact masked lane sum; row
+    t of B is decoded where it is read.  ``lo=False`` (formats whose
+    significand fits the hi plane) skips the two zero lo chains."""
+    bm, bk = ah.shape
+    bn = b_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
+
+    def col(x, t):
+        return jnp.sum(jnp.where(lane == t, x, np.float32(0)), axis=1,
+                       keepdims=True)
+
+    def body(carry):
+        t, hh, hl, lh = carry
+        bh, bl = decode_split_f32(b_ref[pl.ds(t, 1), :], fmt)
+        a_h = col(ah, t)
+        a_hs, bhs = _split12(a_h), _split12(bh)
+        hh = _fma_f32(hh, a_h, bh, a_hs, bhs)
+        if lo:
+            hl = _fma_f32(hl, a_h, bl, a_hs, None)
+            lh = _fma_f32(lh, col(al, t), bh, None, bhs)
+        return t + np.int32(1), hh, hl, lh
+
+    # a while loop keeps the counter int32 (fori_loop's would be int64
+    # under x64, which Mosaic cannot lower)
+    zero = jnp.zeros((bm, bn), jnp.float32)
+    _, hh, hl, lh = jax.lax.while_loop(lambda c: c[0] < bk, body,
+                                       (jnp.int32(0), zero, zero, zero))
+    return hh + (hl + lh) if lo else hh
+
+
 def _kernel(a_ref, b_ref, o_ref, acc_ref, err_ref, *, n_k, compensated,
-            emit_posit, negate, fmt):
+            emit_posit, negate, fmt, chain):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
@@ -208,8 +304,13 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, err_ref, *, n_k, compensated,
             err_ref[...] = jnp.zeros_like(err_ref)
 
     ah, al = decode_split_f32(a_ref[...], fmt)
-    bh, bl = decode_split_f32(b_ref[...], fmt)
-    partial = _matmul_f32(ah, bh) + (_matmul_f32(ah, bl) + _matmul_f32(al, bh))
+    if chain:
+        partial = _chain_split3(ah, al, b_ref, fmt,
+                                lo=fmt.nbits - 3 - fmt.es > 23)
+    else:
+        bh, bl = decode_split_f32(b_ref[...], fmt)
+        partial = (_matmul_f32(ah, bh)
+                   + (_matmul_f32(ah, bl) + _matmul_f32(al, bh)))
 
     if compensated:
         acc = acc_ref[...]
@@ -236,18 +337,22 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, err_ref, *, n_k, compensated,
 # --------------------------------------------------------------------------
 
 def _posit_gemm_call(a_p, b_p, *, bm, bn, bk, mode, interpret, emit_posit,
-                     negate, fmt):
+                     negate, fmt, chain=None):
     m, k = a_p.shape
     k2, n = b_p.shape
     assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0, (
         (m, k, n), (bm, bn, bk))
     compensated = {"split3": False, "split3_comp": True}[mode]
     n_k = k // bk
-    kernel = functools.partial(_kernel, n_k=n_k, compensated=compensated,
-                               emit_posit=emit_posit, negate=negate, fmt=fmt)
     out_dtype = jnp.int32 if emit_posit else jnp.float32
 
     def call(a, b, interpret):
+        # the compiled kernel forms its dots as FMA chains on the VPU, the
+        # interpreter with XLA's dot, which gives the same words on a CPU
+        kernel = functools.partial(
+            _kernel, n_k=n_k, compensated=compensated, emit_posit=emit_posit,
+            negate=negate, fmt=fmt,
+            chain=not interpret if chain is None else chain)
         kwargs = {} if interpret else {
             "compiler_params": pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"))}

@@ -75,3 +75,33 @@ def limb_psum(limbs: jax.Array, nar: jax.Array, axis_name: str):
     limbs = jax.lax.psum(limbs, axis_name)
     nar = jax.lax.psum(jnp.asarray(nar, jnp.int32), axis_name) > 0
     return limbs, nar
+
+
+_PIECE = 22      # bits per int32 wire piece of an int64 limb
+
+
+def limb_psum_scatter(limbs: jax.Array, nar: jax.Array, axis_name: str,
+                      scatter_dimension: int):
+    """``psum_scatter`` of int64 limb planes (tiled) with int32 on the
+    wire: a TPU cannot reduce-scatter int64.  Each limb travels as three
+    int32 pieces, bits [0, 22), [22, 44) and the signed rest; summed over
+    fewer than 2^9 devices none of them overflows int32, and the int64
+    recombination is exact because the merged limbs keep the headroom
+    ``limb_psum`` states.  NaR ORs across devices.
+    """
+    if jax.lax.axis_size(axis_name) >= 1 << (31 - _PIECE):
+        raise ValueError(f"limb_psum_scatter: axis {axis_name!r} has too "
+                         f"many devices for {_PIECE}-bit wire pieces")
+    nl = limbs.shape[-1]
+    mask = (1 << _PIECE) - 1
+    pieces = jnp.concatenate([limbs & mask, (limbs >> _PIECE) & mask,
+                              limbs >> 2 * _PIECE], axis=-1).astype(jnp.int32)
+    pieces = jax.lax.psum_scatter(pieces, axis_name,
+                                  scatter_dimension=scatter_dimension,
+                                  tiled=True).astype(jnp.int64)
+    limbs = (pieces[..., :nl] + (pieces[..., nl:2 * nl] << _PIECE)
+             + (pieces[..., 2 * nl:] << 2 * _PIECE))
+    nar = jax.lax.psum_scatter(jnp.asarray(nar, jnp.int32), axis_name,
+                               scatter_dimension=scatter_dimension,
+                               tiled=True) > 0
+    return limbs, nar

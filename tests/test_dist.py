@@ -119,9 +119,37 @@ print("DONE")
 
 
 @pytest.mark.multi_device
+def test_limb_psum_scatter_is_exact(multi_device):
+    """The k_split reduce-scatter sends int64 limbs as int32 pieces (a
+    TPU cannot reduce-scatter int64): the sums of limbs at the headroom
+    edge come back exact, and NaR flags OR."""
+    out = multi_device(_PRELUDE + """
+from jax.sharding import PartitionSpec as S
+from repro.launch.collectives import limb_psum_scatter
+mesh = make_grid_mesh(1, 8)
+r2 = np.random.default_rng(3)
+x = r2.integers(-2**59, 2**59, (8 * 4, 64, 3), dtype=np.int64)
+x[:, :, 0] = 2**59 - 1
+x[::3, :, 1] = -2**59
+nar = r2.random((8 * 4, 64)) < 0.01
+f = jax.jit(jax.shard_map(
+    lambda l, n: limb_psum_scatter(l, n, "col", 1), mesh=mesh,
+    in_specs=(S("col"), S("col")), out_specs=(S("col"), S("col"))))
+got, got_nar = (np.asarray(v) for v in f(x, nar))
+want = x.reshape(8, 4, 8, 8, 3).sum(axis=0).transpose(1, 0, 2, 3).reshape(
+    32, 8, 3)
+want_nar = nar.reshape(8, 4, 8, 8).any(axis=0).transpose(1, 0, 2).reshape(
+    32, 8)
+assert np.array_equal(got, want) and np.array_equal(got_nar, want_nar)
+print("DONE")
+""")
+    assert "DONE" in out
+
+
+@pytest.mark.multi_device
 def test_pdgemm_limb_psum_k_split(multi_device):
     """The quire limb-plane reduction schedule: deposits on each device's
-    K slab, psum_scatter over int64 limb planes, ONE rounding — plus the
+    K slab, psum_scatter over the limb planes, ONE rounding — plus the
     alpha/beta folding of the quire_exact contract."""
     out = multi_device(_PRELUDE + """
 mesh = make_grid_mesh(2, 2)

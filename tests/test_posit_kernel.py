@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import posit as P
+from repro.core.formats import P16E1, P32E2
 from repro.kernels.ops import rgemm
 from repro.kernels.posit_gemm import decode_split_f32, posit_gemm_f32
 
@@ -137,3 +138,79 @@ def test_xla_quire_refuses_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(ValueError, match="quire_exact.*pallas_split3"):
         rgemm(ap, ap, backend="xla_quire", alpha=0.5)
+
+
+# --------------------------------------------------------------------------
+# the compiled kernel's dot: FMA chains on the VPU, same words as XLA's dot
+# --------------------------------------------------------------------------
+
+def _round_f32(q):
+    """A Fraction correctly rounded to f32 (ties to even)."""
+    from fractions import Fraction
+    f = np.float32(float(q))
+    cands = (np.nextafter(f, np.float32(-np.inf)), f,
+             np.nextafter(f, np.float32(np.inf)))
+    return min(cands, key=lambda c: (abs(Fraction(float(c)) - q),
+                                     int(np.float32(c).view(np.int32)) & 1))
+
+
+def test_fma_f32_is_correctly_rounded():
+    """_fma_f32 == RN(s + x*y) on ties, cancellations and wide exponent
+    gaps, against exact rational arithmetic."""
+    from fractions import Fraction
+    from repro.kernels.posit_gemm import _fma_f32, _split12
+    rng = np.random.default_rng(11)
+    n = 3000
+    x = (rng.standard_normal(n) * np.exp2(rng.integers(-30, 31, n))
+         ).astype(np.float32)
+    y = (rng.standard_normal(n) * np.exp2(rng.integers(-30, 31, n))
+         ).astype(np.float32)
+    s = (rng.standard_normal(n) * np.exp2(rng.integers(-60, 61, n))
+         ).astype(np.float32)
+    third = n // 3                       # s close to -x*y: cancellation
+    s[:third] = (-(x[:third].astype(np.float64) * y[:third])
+                 ).astype(np.float32)
+    ties = slice(third, third + 200)     # s + x*y on a tie of s's grid
+    y[ties] = np.float32(1.0)
+    s[ties] = np.float32(1.0)
+    x[ties] = np.float32(2.0 ** -24) * rng.choice([1, -1, 3, -3], 200
+                                                  ).astype(np.float32)
+    # just off a tie by a product bit below ph: only the sticky bit of the
+    # round-to-odd step breaks it the right way
+    odd = np.float32(1 + 2.0 ** -23)
+    for i, (sg_s, sg_x) in enumerate(((1, 1), (-1, -1), (1, -1), (-1, 1))):
+        s[third + 200 + i] = sg_s * odd
+        x[third + 200 + i] = sg_x * odd
+        y[third + 200 + i] = np.float32((1 - 2.0 ** -23) * 2.0 ** -24)
+    got = np.asarray(_fma_f32(jnp.asarray(s), jnp.asarray(x),
+                              jnp.asarray(y), _split12(jnp.asarray(x)),
+                              _split12(jnp.asarray(y))))
+    want = np.array([_round_f32(Fraction(float(a)) + Fraction(float(b))
+                                * Fraction(float(c)))
+                     for a, b, c in zip(s, x, y)], np.float32)
+    assert np.array_equal(got.view(np.int32), want.view(np.int32))
+
+
+@pytest.mark.parametrize("fmt", [P32E2, P16E1], ids=lambda f: f.name)
+@pytest.mark.parametrize("mode", ["split3", "split3_comp"])
+@pytest.mark.parametrize("spread", [0, 20])
+def test_chain_kernel_matches_dot_kernel(fmt, mode, spread):
+    """The compiled kernel forms its dots as FMA chains (a TPU's MXU
+    rounds differently); run in the interpreter here, the chain gives
+    the words and f32 accumulators of the XLA-dot kernel bit for bit."""
+    import jax
+    from repro.kernels.posit_gemm import _posit_gemm_call
+    rng = np.random.default_rng(spread)
+    x = rng.standard_normal((128, 256)) * np.exp2(
+        rng.integers(-spread, spread + 1, (128, 256)))
+    y = rng.standard_normal((256, 256)) * np.exp2(
+        rng.integers(-spread, spread + 1, (256, 256)))
+    y[128:192] = -y[64:128] * (1 + 2.0 ** -20)        # cancellation
+    ap, bp = (jax.jit(lambda v: P.from_float64_bits(v, fmt))(v.view(np.int64))
+              for v in (x, y))
+    for emit_posit, negate in ((False, False), (True, True)):
+        kw = dict(bm=128, bn=128, bk=128, mode=mode, interpret=True,
+                  emit_posit=emit_posit, negate=negate, fmt=fmt)
+        dot = np.asarray(_posit_gemm_call(ap, bp, chain=False, **kw))
+        chain = np.asarray(_posit_gemm_call(ap, bp, chain=True, **kw))
+        assert np.array_equal(dot.view(np.int32), chain.view(np.int32))
