@@ -54,6 +54,7 @@ from repro.kernels import ref
 from repro.kernels.posit_gemm import posit_gemm, posit_gemm_f32
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
+from repro.obs import scopes as _scopes
 from repro.obs import trace as _obs_trace
 from repro.quire import quire_gemm
 
@@ -85,7 +86,15 @@ def _rgemm_jit(a_p: jax.Array, b_p: jax.Array, c_p: jax.Array | None = None,
                alpha=1.0, beta=0.0, *, trans_a: bool = False,
                trans_b: bool = False, backend: str = "xla_quire",
                block: int = 128, fmt: PositFormat = P32E2) -> jax.Array:
-    """The jitted GEMM program (see ``rgemm``, the public entry point)."""
+    """The jitted GEMM program (see ``rgemm``, the public entry point),
+    all of it under the ``posit.update`` scope."""
+    with jax.named_scope(_scopes.UPDATE):
+        return _rgemm_body(a_p, b_p, c_p, alpha, beta, trans_a, trans_b,
+                           backend, block, fmt)
+
+
+def _rgemm_body(a_p, b_p, c_p, alpha, beta, trans_a, trans_b, backend,
+                block, fmt):
     a_p = jnp.asarray(a_p, jnp.int32)
     b_p = jnp.asarray(b_p, jnp.int32)
     if trans_a:
@@ -167,26 +176,26 @@ def rgemm(a_p: jax.Array, b_p: jax.Array, c_p: jax.Array | None = None,
           fmt: PositFormat = P32E2) -> jax.Array:
     """Posit GEMM returning posit words (int32) in format ``fmt``.
 
-    Observability (repro.obs): with a collector open and CONCRETE
-    operands, the call is wrapped in a span and the operand/result words
-    are summarized (golden-zone occupancy, regime widths).  With no
-    collector — or when this call is being traced into an outer jitted
-    program (decomp/qr/pblas bodies), where the operands are tracers —
-    the gate is resolved at the Python level and the exact same jitted
-    program as before dispatches, so lowered programs are unchanged.
+    Observability (repro.obs): the call runs under the host span
+    ``posit.rgemm``.  With a collector open and CONCRETE operands, the
+    span is timed and the operand/result words are summarized
+    (golden-zone occupancy, regime widths).  With no collector — or when
+    this call is being traced into an outer jitted program
+    (decomp/qr/pblas bodies), where the operands are tracers — the gate
+    is resolved at the Python level and the exact same jitted program as
+    before dispatches, so lowered programs are unchanged.
     """
-    if not _obs_numerics.active(a_p, b_p, c_p if c_p is not None else a_p):
-        return _rgemm_jit(a_p, b_p, c_p, alpha, beta, trans_a=trans_a,
-                          trans_b=trans_b, backend=backend, block=block,
-                          fmt=fmt)
     m = a_p.shape[1] if trans_a else a_p.shape[0]
     k = a_p.shape[0] if trans_a else a_p.shape[1]
     n = b_p.shape[0] if trans_b else b_p.shape[1]
-    with _obs_trace.span("rgemm", m=int(m), k=int(k), n=int(n),
+    with _obs_trace.span("posit.rgemm", m=int(m), k=int(k), n=int(n),
                          backend=backend, fmt=fmt.name):
         out = _rgemm_jit(a_p, b_p, c_p, alpha, beta, trans_a=trans_a,
                          trans_b=trans_b, backend=backend, block=block,
                          fmt=fmt)
+        if not _obs_numerics.active(a_p, b_p,
+                                    c_p if c_p is not None else a_p):
+            return out
         _obs_metrics.inc("rgemm.calls")
         _obs_metrics.inc("rgemm.macs", float(m) * float(k) * float(n))
         _obs_numerics.record_numerics("rgemm.a", a_p, fmt)

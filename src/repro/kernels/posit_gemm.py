@@ -345,6 +345,8 @@ def _posit_gemm_call(a_p, b_p, *, bm, bn, bk, mode, interpret, emit_posit,
     compensated = {"split3": False, "split3_comp": True}[mode]
     n_k = k // bk
     out_dtype = jnp.int32 if emit_posit else jnp.float32
+    # the kernel's own name in a profile: posit_gemm_p32e2, ..._f32_p32e2
+    name = f"posit_gemm{'' if emit_posit else '_f32'}_{fmt.name}"
 
     def call(a, b, interpret):
         # the compiled kernel forms its dots as FMA chains on the VPU, the
@@ -358,6 +360,7 @@ def _posit_gemm_call(a_p, b_p, *, bm, bn, bk, mode, interpret, emit_posit,
                 dimension_semantics=("parallel", "parallel", "arbitrary"))}
         return pl.pallas_call(
             kernel,
+            name=name,
             grid=(m // bm, n // bn, n_k),
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -51,6 +51,7 @@ from repro.kernels.ops import rgemm
 from repro.lapack.blas import rtrsm_left_lower, rtrsm_right_lowerT
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
+from repro.obs import scopes as _scopes
 from repro.obs import trace as _obs_trace
 
 _FMT = P32E2
@@ -201,16 +202,20 @@ def _rpotrf_body(a_p: jax.Array, nb: int, gemm_backend: str,
     tel = []
     for j in range(0, n, nb):
         w = min(nb, n - j)
-        l11 = panel(a[j:j + w, j:j + w], fmt=fmt)
-        a = a.at[j:j + w, j:j + w].set(l11)
+        with jax.named_scope(_scopes.PANEL):
+            l11 = panel(a[j:j + w, j:j + w], fmt=fmt)
+            a = a.at[j:j + w, j:j + w].set(l11)
         step = {"panel": _obs_numerics.step_stats(l11, fmt)} if collect \
             else None
         if j + w < n:
-            a21 = rtrsm_right_lowerT(a[j + w:, j:j + w], l11, fmt=fmt)
-            a = a.at[j + w:, j:j + w].set(a21)
-            upd = rgemm(a21, a21, a[j + w:, j + w:], alpha=-1.0, beta=1.0,
-                        trans_b=True, backend=gemm_backend, fmt=fmt)
-            a = a.at[j + w:, j + w:].set(upd)
+            with jax.named_scope(_scopes.TRSM):
+                a21 = rtrsm_right_lowerT(a[j + w:, j:j + w], l11, fmt=fmt)
+                a = a.at[j + w:, j:j + w].set(a21)
+            with jax.named_scope(_scopes.UPDATE):
+                upd = rgemm(a21, a21, a[j + w:, j + w:], alpha=-1.0,
+                            beta=1.0, trans_b=True, backend=gemm_backend,
+                            fmt=fmt)
+                a = a.at[j + w:, j + w:].set(upd)
             if collect:
                 step["update"] = _obs_numerics.step_stats(upd, fmt)
         if collect:
@@ -234,12 +239,10 @@ def _rgetrf_body(a_p: jax.Array, nb: int, gemm_backend: str,
     tel = []
     for j in range(0, min(m, n), nb):
         w = min(nb, min(m, n) - j)
-        panel, piv_loc = panel_fn(a[j:, j:j + w], w, fmt=fmt)
+        with jax.named_scope(_scopes.PANEL):
+            panel, piv_loc = panel_fn(a[j:, j:j + w], w, fmt=fmt)
         if collect:
             tel.append({"panel": _obs_numerics.step_stats(panel, fmt)})
-        # apply the panel's row swaps to the rest of the matrix
-        left = a[j:, :j]
-        right = a[j:, j + w:]
 
         def apply_swaps(blk):
             def one(b, kp):
@@ -249,22 +252,29 @@ def _rgetrf_body(a_p: jax.Array, nb: int, gemm_backend: str,
             blk, _ = jax.lax.scan(one, blk, (jnp.arange(w), piv_loc))
             return blk
 
-        if j > 0:
-            left = apply_swaps(left)
-            a = a.at[j:, :j].set(left)
+        with jax.named_scope(_scopes.SWAP):
+            # apply the panel's row swaps to the rest of the matrix
+            left = a[j:, :j]
+            right = a[j:, j + w:]
+            if j > 0:
+                left = apply_swaps(left)
+                a = a.at[j:, :j].set(left)
+            if j + w < n:
+                right = apply_swaps(right)
+        with jax.named_scope(_scopes.PANEL):
+            a = a.at[j:, j:j + w].set(panel)
+            ipiv = ipiv.at[j:j + w].set(piv_loc + j)
         if j + w < n:
-            right = apply_swaps(right)
-        a = a.at[j:, j:j + w].set(panel)
-        ipiv = ipiv.at[j:j + w].set(piv_loc + j)
-        if j + w < n:
-            u12 = rtrsm_left_lower(panel[:w, :], right[:w, :], unit_diag=True,
-                                   fmt=fmt)
-            a = a.at[j:j + w, j + w:].set(u12)
+            with jax.named_scope(_scopes.TRSM):
+                u12 = rtrsm_left_lower(panel[:w, :], right[:w, :],
+                                       unit_diag=True, fmt=fmt)
+                a = a.at[j:j + w, j + w:].set(u12)
             if j + w < m:
-                l21 = panel[w:, :]
-                upd = rgemm(l21, u12, right[w:, :], alpha=-1.0, beta=1.0,
-                            backend=gemm_backend, fmt=fmt)
-                a = a.at[j + w:, j + w:].set(upd)
+                with jax.named_scope(_scopes.UPDATE):
+                    l21 = panel[w:, :]
+                    upd = rgemm(l21, u12, right[w:, :], alpha=-1.0,
+                                beta=1.0, backend=gemm_backend, fmt=fmt)
+                    a = a.at[j + w:, j + w:].set(upd)
                 if collect:
                     tel[-1]["update"] = _obs_numerics.step_stats(upd, fmt)
     return (a, ipiv, tel) if collect else (a, ipiv)
@@ -304,32 +314,35 @@ def rpotrf(a_p: jax.Array, nb: int = 64, gemm_backend: str = "xla_quire",
     runs the collect-variant program instead — same factorization ops
     plus per-block-step golden-zone/regime telemetry (bit-identical L,
     separate jit cache entry); otherwise dispatches the exact program
-    this function has always been.
+    this function has always been.  Either way the call runs under the
+    host span ``posit.rpotrf`` (repro.obs.scopes).
     """
-    if _obs_numerics.active(a_p):
-        with _obs_trace.span("rpotrf", n=int(a_p.shape[0]), nb=nb,
-                             backend=gemm_backend, fmt=fmt.name):
-            out, tel = _rpotrf_collect(a_p, nb=nb,
-                                       gemm_backend=gemm_backend, fmt=fmt)
-        _obs_numerics.emit_factor_steps("rpotrf", tel)
-        return out
-    return _rpotrf_jit(a_p, nb=nb, gemm_backend=gemm_backend, fmt=fmt)
+    with _obs_trace.span("posit.rpotrf", n=int(a_p.shape[0]), nb=nb,
+                         backend=gemm_backend, fmt=fmt.name):
+        if not _obs_numerics.active(a_p):
+            return _rpotrf_jit(a_p, nb=nb, gemm_backend=gemm_backend,
+                               fmt=fmt)
+        out, tel = _rpotrf_collect(a_p, nb=nb, gemm_backend=gemm_backend,
+                                   fmt=fmt)
+    _obs_numerics.emit_factor_steps("rpotrf", tel)
+    return out
 
 
 def rgetrf(a_p: jax.Array, nb: int = 64, gemm_backend: str = "xla_quire",
            fmt: PositFormat = P32E2):
     """Blocked partial-pivot LU, ONE XLA dispatch; returns (LU, ipiv).
-    Observability contract as in ``rpotrf``."""
-    if _obs_numerics.active(a_p):
-        with _obs_trace.span("rgetrf", m=int(a_p.shape[0]),
-                             n=int(a_p.shape[1]), nb=nb,
-                             backend=gemm_backend, fmt=fmt.name):
-            lu, ipiv, tel = _rgetrf_collect(a_p, nb=nb,
-                                            gemm_backend=gemm_backend,
-                                            fmt=fmt)
-        _obs_numerics.emit_factor_steps("rgetrf", tel)
-        return lu, ipiv
-    return _rgetrf_jit(a_p, nb=nb, gemm_backend=gemm_backend, fmt=fmt)
+    Observability contract as in ``rpotrf``; host span
+    ``posit.rgetrf``."""
+    with _obs_trace.span("posit.rgetrf", m=int(a_p.shape[0]),
+                         n=int(a_p.shape[1]), nb=nb, backend=gemm_backend,
+                         fmt=fmt.name):
+        if not _obs_numerics.active(a_p):
+            return _rgetrf_jit(a_p, nb=nb, gemm_backend=gemm_backend,
+                               fmt=fmt)
+        lu, ipiv, tel = _rgetrf_collect(a_p, nb=nb,
+                                        gemm_backend=gemm_backend, fmt=fmt)
+    _obs_numerics.emit_factor_steps("rgetrf", tel)
+    return lu, ipiv
 
 
 def rpotrf_loop(a_p: jax.Array, nb: int = 64,

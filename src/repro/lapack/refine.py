@@ -60,6 +60,7 @@ from repro.core.formats import P16E1, P32E2, PositFormat
 from repro.lapack import decomp, solve
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
+from repro.obs import scopes as _scopes
 from repro.obs import trace as _obs_trace
 from repro.quire import (q_to_posit, qadd_posit, quire_dot, quire_from_posit)
 
@@ -70,13 +71,15 @@ def residual_quire(a_p: jax.Array, x_p: jax.Array, b_p: jax.Array,
                    fmt: PositFormat = P32E2) -> jax.Array:
     """r = b - A (x + x_lo) with each component an exact fused dot product
     rounded once to posit (the quire residual at the heart of the
-    refinement).  ``x_lo_p`` extends x to an unevaluated posit pair."""
-    if x_lo_p is None:
-        aa, xx = a_p, x_p
-    else:
-        aa = jnp.concatenate([a_p, a_p], axis=1)
-        xx = jnp.concatenate([x_p, x_lo_p])
-    return quire_dot(aa, xx[None, :], fmt, init_p=b_p, negate=True)
+    refinement).  ``x_lo_p`` extends x to an unevaluated posit pair.
+    Runs under the ``posit.quire_residual`` scope."""
+    with jax.named_scope(_scopes.QUIRE_RESIDUAL):
+        if x_lo_p is None:
+            aa, xx = a_p, x_p
+        else:
+            aa = jnp.concatenate([a_p, a_p], axis=1)
+            xx = jnp.concatenate([x_p, x_lo_p])
+        return quire_dot(aa, xx[None, :], fmt, init_p=b_p, negate=True)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt",))
@@ -85,6 +88,20 @@ def pair_to_float64(x_p: jax.Array, x_lo_p: jax.Array,
     """Evaluate an unevaluated posit pair in binary64 (|lo| <~ ulp(hi), so
     the f64 sum is exact to f64 precision)."""
     return posit.to_float64(x_p, fmt) + posit.to_float64(x_lo_p, fmt)
+
+
+def _pair_update(hi, lo, d, fmt: PositFormat):
+    """The exact compensated update of the pair: q = hi + lo + d held
+    exactly in the quire; hi' = round(q); lo' = round(q - hi') (q - hi'
+    is exact).  Returns (hi', lo', q), under the ``posit.pair_update``
+    scope."""
+    with jax.named_scope(_scopes.PAIR_UPDATE):
+        q = quire_from_posit(hi, fmt)
+        q = qadd_posit(q, lo, fmt)
+        q = qadd_posit(q, d, fmt)
+        hi2 = q_to_posit(q, fmt)
+        lo2 = q_to_posit(qadd_posit(q, hi2, fmt, negate=True), fmt)
+    return hi2, lo2, q
 
 
 def refine_pair(solve_fn, residual_fn, b_col: jax.Array, iters: int,
@@ -124,13 +141,7 @@ def refine_pair(solve_fn, residual_fn, b_col: jax.Array, iters: int,
         hi, lo = carry
         r = residual_fn(hi, lo, b_col)
         d = solve_fn(r)
-        # exact compensated update: q = hi + lo + d held exactly in the
-        # quire; hi' = round(q); lo' = round(q - hi') (q - hi' is exact)
-        q = quire_from_posit(hi, fmt)
-        q = qadd_posit(q, lo, fmt)
-        q = qadd_posit(q, d, fmt)
-        hi2 = q_to_posit(q, fmt)
-        lo2 = q_to_posit(qadd_posit(q, hi2, fmt, negate=True), fmt)
+        hi2, lo2, _ = _pair_update(hi, lo, d, fmt)
         return (hi2, lo2), None
 
     (x_hi, x_lo), _ = jax.lax.scan(body, (x_hi, x_lo), None, length=iters)
@@ -157,11 +168,7 @@ def _refine_pair_obs(solve_fn, residual_fn, b_col: jax.Array, iters: int,
         with _obs_trace.span("ir.sweep", sweep=i):
             r = residual_fn(x_hi, x_lo, b_col)
             d = solve_fn(r)
-            q = quire_from_posit(x_hi, fmt)
-            q = qadd_posit(q, x_lo, fmt)
-            q = qadd_posit(q, d, fmt)
-            hi2 = q_to_posit(q, fmt)
-            lo2 = q_to_posit(qadd_posit(q, hi2, fmt, negate=True), fmt)
+            hi2, lo2, q = _pair_update(x_hi, x_lo, d, fmt)
 
             r_norm = float(jnp.max(jnp.abs(posit.to_float64(r, fmt))))
             if r0_norm is None:
@@ -205,16 +212,20 @@ def rgesv_ir(a_p: jax.Array, b_p: jax.Array, iters: int = 3, nb: int = 32,
     (n, nrhs) (vmapped over columns).  A batched a_p of shape
     (batch, n, n) (with matching leading axis on b) vmaps the whole
     driver — factorizations and refinement sweeps run as one batched
-    program on top of the single-dispatch ``rgetrf``.
+    program on top of the single-dispatch ``rgetrf``.  Runs under the
+    host span ``posit.rgesv_ir``.
     """
-    a_p = jnp.asarray(a_p, jnp.int32)
-    if a_p.ndim == 3:
-        return jax.vmap(lambda a, b: rgesv_ir(a, b, iters, nb, gemm_backend,
-                                              fmt)
-                        )(a_p, jnp.asarray(b_p, jnp.int32))
-    lu, ipiv = decomp.rgetrf(a_p, nb=nb, gemm_backend=gemm_backend, fmt=fmt)
-    solve_fn = lambda r: solve.rgetrs(lu, ipiv, r, quire=True, fmt=fmt)
-    return _driver(a_p, b_p, solve_fn, iters, fmt), (lu, ipiv)
+    with _obs_trace.span("posit.rgesv_ir", iters=iters, nb=nb,
+                         backend=gemm_backend, fmt=fmt.name):
+        a_p = jnp.asarray(a_p, jnp.int32)
+        if a_p.ndim == 3:
+            return jax.vmap(lambda a, b: rgesv_ir(a, b, iters, nb,
+                                                  gemm_backend, fmt)
+                            )(a_p, jnp.asarray(b_p, jnp.int32))
+        lu, ipiv = decomp.rgetrf(a_p, nb=nb, gemm_backend=gemm_backend,
+                                 fmt=fmt)
+        solve_fn = lambda r: solve.rgetrs(lu, ipiv, r, quire=True, fmt=fmt)
+        return _driver(a_p, b_p, solve_fn, iters, fmt), (lu, ipiv)
 
 
 def rposv_ir(a_p: jax.Array, b_p: jax.Array, iters: int = 3, nb: int = 32,
@@ -222,16 +233,18 @@ def rposv_ir(a_p: jax.Array, b_p: jax.Array, iters: int = 3, nb: int = 32,
     """Cholesky-based SPD solve with quire-exact iterative refinement.
 
     Returns ((x_hi, x_lo), l); same conventions (including batched a_p)
-    as ``rgesv_ir``.
+    as ``rgesv_ir``; host span ``posit.rposv_ir``.
     """
-    a_p = jnp.asarray(a_p, jnp.int32)
-    if a_p.ndim == 3:
-        return jax.vmap(lambda a, b: rposv_ir(a, b, iters, nb, gemm_backend,
-                                              fmt)
-                        )(a_p, jnp.asarray(b_p, jnp.int32))
-    l_p = decomp.rpotrf(a_p, nb=nb, gemm_backend=gemm_backend, fmt=fmt)
-    solve_fn = lambda r: solve.rpotrs(l_p, r, quire=True, fmt=fmt)
-    return _driver(a_p, b_p, solve_fn, iters, fmt), l_p
+    with _obs_trace.span("posit.rposv_ir", iters=iters, nb=nb,
+                         backend=gemm_backend, fmt=fmt.name):
+        a_p = jnp.asarray(a_p, jnp.int32)
+        if a_p.ndim == 3:
+            return jax.vmap(lambda a, b: rposv_ir(a, b, iters, nb,
+                                                  gemm_backend, fmt)
+                            )(a_p, jnp.asarray(b_p, jnp.int32))
+        l_p = decomp.rpotrf(a_p, nb=nb, gemm_backend=gemm_backend, fmt=fmt)
+        solve_fn = lambda r: solve.rpotrs(l_p, r, quire=True, fmt=fmt)
+        return _driver(a_p, b_p, solve_fn, iters, fmt), l_p
 
 
 # --------------------------------------------------------------------------
@@ -417,12 +430,7 @@ def refine_pair_monitored(solve_fn, residual_fn, b_col: jax.Array,
             flat = 0
         best = min(best, r_norm)
         d = solve_fn(r)
-        q = quire_from_posit(x_hi, fmt)
-        q = qadd_posit(q, x_lo, fmt)
-        q = qadd_posit(q, d, fmt)
-        hi2 = q_to_posit(q, fmt)
-        lo2 = q_to_posit(qadd_posit(q, hi2, fmt, negate=True), fmt)
-        x_hi, x_lo = hi2, lo2
+        x_hi, x_lo, _ = _pair_update(x_hi, x_lo, d, fmt)
         sweeps = i + 1
     info = {"outcome": outcome, "sweeps": sweeps, "r_norm": r_norm,
             "r_norm0": r0_norm}

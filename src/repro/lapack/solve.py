@@ -5,6 +5,9 @@ relative backward error).
 ``quire=True`` switches both substitution sweeps to the quire-exact
 variants (one rounding per solved component; lapack/blas.py) — the
 building block of the iterative-refinement drivers in lapack/refine.py.
+The sweeps of ``rgetrs``/``rpotrs`` run under the ``posit.sweep`` or
+``posit.quire_sweep`` scope, ``rgetrs``' pivot scan under ``posit.swap``
+(repro.obs.scopes).
 ``fmt`` selects the posit format of the factors/right-hand side (static,
 default Posit(32,2)); the mixed-precision drivers run these in p16e1.
 """
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 from repro.core.formats import P32E2, PositFormat
 from repro.lapack.blas import (rtrsv_lower, rtrsv_lower_quire, rtrsv_upper,
                                rtrsv_upper_quire)
+from repro.obs import scopes as _scopes
 
 
 def _sweeps(quire: bool):
@@ -48,8 +52,9 @@ def rpotrs(l_p: jax.Array, b_p: jax.Array, quire: bool = False,
            fmt: PositFormat = P32E2) -> jax.Array:
     """Solve (L L^T) x = b in posit: forward then backward substitution."""
     lower, upper = _sweeps(quire)
-    y = lower(l_p, b_p, unit_diag=False, fmt=fmt)
-    return upper(l_p.T, y, unit_diag=False, fmt=fmt)
+    with jax.named_scope(_scopes.sweep(quire)):
+        y = lower(l_p, b_p, unit_diag=False, fmt=fmt)
+        return upper(l_p.T, y, unit_diag=False, fmt=fmt)
 
 
 @functools.partial(jax.jit, static_argnames=("quire", "fmt"))
@@ -61,10 +66,12 @@ def rgetrs(lu_p: jax.Array, ipiv: jax.Array, b_p: jax.Array,
         bk, bp_ = b[k], b[p]
         return b.at[k].set(bp_).at[p].set(bk), None
 
-    b, _ = jax.lax.scan(one, b_p, (jnp.arange(ipiv.shape[0]), ipiv))
+    with jax.named_scope(_scopes.SWAP):
+        b, _ = jax.lax.scan(one, b_p, (jnp.arange(ipiv.shape[0]), ipiv))
     lower, upper = _sweeps(quire)
-    y = lower(lu_p, b, unit_diag=True, fmt=fmt)
-    return upper(lu_p, y, unit_diag=False, fmt=fmt)
+    with jax.named_scope(_scopes.sweep(quire)):
+        y = lower(lu_p, b, unit_diag=True, fmt=fmt)
+        return upper(lu_p, y, unit_diag=False, fmt=fmt)
 
 
 def spotrs(l32: jax.Array, b32: jax.Array) -> jax.Array:

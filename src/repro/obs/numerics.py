@@ -17,8 +17,9 @@ Two call shapes:
 * ``collect_numerics(words, fmt)`` / ``encode_round_stats(x, fmt)`` /
   ``quire_carry_stats(limbs)`` — jitted, return device scalars/arrays;
   usable standalone or from inside larger jitted telemetry bodies.
-* ``record_*`` helpers — host-side, gate on ``active(...)`` and push
-  results into the open ``obs.scoped()`` collectors.
+* ``record_numerics`` / ``emit_factor_steps`` — host-side, gate on
+  ``active(...)`` or an open collector and push results into the open
+  ``obs.scoped()`` collectors.
 
 ``active(*arrays)`` is the zero-cost gate used by every instrumented
 library entry point: it is False when no collector is open OR when any
@@ -203,27 +204,6 @@ def record_numerics(name: str, words, fmt: PositFormat = P32E2):
                           _hist_to_dict(st["regime_hist"]))
     _metrics.observe_hist(f"{name}.scale",
                           _hist_to_dict(st["scale_hist"], -fmt.max_scale))
-    return st
-
-
-def record_encode_stats(name: str, x, fmt: PositFormat = P32E2):
-    """Record encode-path rounding counters for f64 carrier values."""
-    if not active(x):
-        return None
-    st = encode_round_stats(x, fmt)
-    _metrics.inc(f"{name}.encodes", st["total"])
-    _metrics.inc(f"{name}.rounded", st["rounded"])
-    _metrics.inc(f"{name}.sticky", st["sticky"])
-    _metrics.inc(f"{name}.saturated", st["saturated"])
-    return st
-
-
-def record_quire_carries(name: str, limbs):
-    """Record quire limb-carry counts for a redundant limb state."""
-    if not active(limbs):
-        return None
-    st = quire_carry_stats(limbs)
-    _metrics.inc(f"{name}.limb_carries", st["total"])
     return st
 
 

@@ -19,6 +19,7 @@ The contract under test, in order of importance:
 from __future__ import annotations
 
 import json
+import re
 from fractions import Fraction
 
 import numpy as np
@@ -30,7 +31,7 @@ from repro.core import posit
 from repro.core.formats import P8E2, P16E1, P32E2
 from repro import obs
 from repro.kernels import ops
-from repro.lapack import decomp, qr, refine
+from repro.lapack import decomp, qr, refine, solve
 from repro.launch import hlo_analysis
 
 import posit_oracle
@@ -69,6 +70,112 @@ def test_disabled_lowering_identical():
     w3 = jax.jit(lambda x: ops.rgemm(x, x)).lower(a).as_text()
     d3 = jax.jit(lambda x: ops._rgemm_jit(x, x)).lower(a).as_text()
     assert w3 == d3
+
+
+# the scopes each lowered program carries in its op metadata (n = 64,
+# nb = 16): every layer it runs, and no other
+_SCOPED = {
+    "rgetrf": {"posit.panel", "posit.swap", "posit.trsm", "posit.update"},
+    "rpotrf": {"posit.panel", "posit.trsm", "posit.update"},
+    "rgetrs": {"posit.swap", "posit.sweep"},
+    "rgetrs_quire": {"posit.swap", "posit.quire_sweep"},
+    "rgemm": {"posit.update"},
+    "rgesv_ir": {"posit.panel", "posit.swap", "posit.trsm", "posit.update",
+                 "posit.quire_sweep", "posit.quire_residual",
+                 "posit.pair_update"},
+}
+_SCOPE = re.compile(r'["/](posit\.[a-z_]+)/')
+
+
+def _scoped_programs(n=64, nb=16):
+    rng = np.random.default_rng(4)
+    a = _pm(rng, (n, n))
+    spd = ops.rgemm(a, a, trans_b=True)
+    b = _pm(rng, (n,))
+    ipiv = jnp.arange(n, dtype=jnp.int32)
+    return {
+        "rgetrf": (lambda x: decomp.rgetrf(x, nb=nb), (a,)),
+        "rpotrf": (lambda x: decomp.rpotrf(x, nb=nb), (spd,)),
+        "rgetrs": (lambda lu, p, y: solve.rgetrs(lu, p, y), (a, ipiv, b)),
+        "rgetrs_quire": (lambda lu, p, y: solve.rgetrs(lu, p, y, quire=True),
+                         (a, ipiv, b)),
+        "rgemm": (lambda x, y: ops.rgemm(x, y, x, alpha=-1.0, beta=1.0),
+                  (a, a)),
+        "rgesv_ir": (lambda x, y: refine.rgesv_ir(x, y, iters=2, nb=nb),
+                     (a, b)),
+    }
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPED))
+def test_lowered_program_carries_its_layer_scopes(program):
+    fn, args = _scoped_programs()[program]
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert set(_SCOPE.findall(text)) == _SCOPED[program]
+    assert _SCOPED[program] <= set(obs.scopes.ALL)
+
+
+def test_scopes_are_metadata_only():
+    """Without debug info the lowered program is the same with the
+    scopes left out (jax.named_scope made a no-op)."""
+    fn, args = _scoped_programs(n=32)["rgesv_ir"]
+    with_scopes = jax.jit(fn).lower(*args).as_text()
+    import contextlib
+    from unittest import mock
+    jax.clear_caches()                  # retrace the inner jitted helpers
+    try:
+        with mock.patch.object(jax, "named_scope",
+                               lambda name: contextlib.nullcontext()):
+            bare = jax.jit(fn).lower(*args)
+    finally:
+        jax.clear_caches()
+    assert "posit.swap" not in bare.as_text(debug_info=True)
+    assert bare.as_text() == with_scopes
+
+
+def test_pallas_kernel_is_named_for_a_tpu():
+    a = _pm(np.random.default_rng(6), (128, 128))
+    text = jax.jit(
+        lambda x: ops.rgemm(x, x, x, alpha=-1.0, beta=1.0,
+                            backend="pallas_split3")
+    ).trace(a).lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "posit_gemm_f32_p32e2"' in text
+
+
+def test_entry_points_open_host_spans():
+    """Each public entry opens its ``posit.<entry>`` span on the plain
+    path too: a profiler annotation, entered here while the entry is
+    traced (``jax.eval_shape``, nothing compiles)."""
+    rng = np.random.default_rng(8)
+    a = _pm(rng, (32, 32))
+    b = _pm(rng, (32,))
+    annotated = []
+
+    class _Annotation:
+        def __init__(self, name, **kw):
+            annotated.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    spd = ops.rgemm(a, a, trans_b=True)
+    calls = {
+        "posit.rgetrf": lambda: decomp.rgetrf(a, nb=16),
+        "posit.rpotrf": lambda: decomp.rpotrf(spd, nb=16),
+        "posit.rgemm": lambda: ops.rgemm(a, a),
+        "posit.rgesv_ir": lambda: refine.rgesv_ir(a, b, iters=1, nb=16),
+        "posit.rposv_ir": lambda: refine.rposv_ir(spd, b, iters=1, nb=16),
+    }
+    from unittest import mock
+    with mock.patch.object(jax.profiler, "TraceAnnotation", _Annotation):
+        for name, call in calls.items():
+            annotated.clear()
+            jax.eval_shape(call)
+            assert annotated[0] == name
+            assert "ir.sweep" not in annotated  # no collector: plain path
+    assert "posit.rpotrf" in annotated          # rposv_ir's factorization
 
 
 def test_disabled_recorders_are_noops():
