@@ -28,32 +28,98 @@ def _div(a, b, fmt: PositFormat = P32E2):
     return posit.div(a, b, fmt, backend="fast")
 
 
+# --------------------------------------------------------------------------
+# trapezoid trimming (DESIGN.md §6.2): a rank-1 scan whose step k changes
+# only the rows past k walks its rows in tiles, and replays into each
+# tile only the steps that reach it.  A TPU holds an array's second-last
+# dimension on 8 sublanes, so a tile is a whole number of sublane tiles;
+# how many is each scan's fastest on a v5e (DESIGN.md §6.2).
+# --------------------------------------------------------------------------
+
+SUBLANES = 8
+TRSM_TILE = 2 * SUBLANES    # rows per tile of ``rtrsm_left_lower``
+PANEL_TILE = 4 * SUBLANES   # panel columns per tile of ``decomp.getf2``
+
+
+def _steps(n: int, t: int) -> int:
+    """Row-steps of a scan over n rows in t-row tiles: tile i holds t
+    rows and runs min((i + 1) t, n) steps."""
+    return t * sum(min(i * t, n) for i in range(1, -(-n // t) + 1))
+
+
+def row_tiles(n: int, tile: int) -> tuple[int, int]:
+    """(rows per tile, tiles) of a trimmed scan over n rows: ``tile``-row
+    tiles where they compute less than the masked scan, else one tile of
+    all n rows, which is the masked scan."""
+    t = tile if _steps(n, tile) < n * n else n
+    return t, -(-n // t)
+
+
+def tile_rows(i, t: int, n: int):
+    """Tile i of a trimmed scan over n rows of t-row tiles: (first row
+    it solves, first row it holds, steps it runs).  The last tile of an
+    n that t does not divide is shifted up to end at row n, so every
+    tile has one shape; the rows it holds above its first solved row
+    are finished and stay as they are."""
+    own0 = i * t
+    return own0, jnp.minimum(own0, n - t), jnp.minimum(own0 + t, n)
+
+
+def trimmed_steps(n: int, tile: int) -> int:
+    """Row-steps a trimmed scan over n rows in ``tile``-row tiles
+    computes (the masked scan computed n * n)."""
+    return _steps(n, row_tiles(n, tile)[0])
+
+
 @functools.partial(jax.jit, static_argnames=("unit_diag", "fmt"))
 def rtrsm_left_lower(l_p: jax.Array, b_p: jax.Array, unit_diag: bool = True,
                      fmt: PositFormat = P32E2) -> jax.Array:
     """Solve L X = B, L (n,n) lower-triangular posit, B (n, m) posit.
 
-    Forward substitution in rank-1-update order: n steps, each a
-    vectorized posit mul+sub over the remaining rows.  Fused-chain
-    execution (core/posit.py): L and B decode once, each scalar op
-    is still individually posit-rounded, words are packed once at exit —
-    bit-identical to per-op fast-backend words.
+    Forward substitution in rank-1-update order: step k solves row k,
+    x_k = B[k] (/ L[k, k]), and every row i > k takes
+    B[i] <- B[i] - L[i, k] * x_k.  Fused-chain execution (core/posit.py):
+    L and B decode once, each scalar op is still individually
+    posit-rounded, words are packed once at exit — bit-identical to
+    per-op fast-backend words.
+
+    Trapezoid-trimmed: the rows walk in ``TRSM_TILE``-row tiles, and tile
+    [r0, r0 + t) runs steps k < r0 + t only, on its own t rows: k < r0
+    replays a finished row x_k into it, k >= r0 solves its row k.  Every
+    element still takes B[i] - L[i, k] * x_k for each k < i, in ascending
+    k, with the same operands, and then is solved, so the words are the
+    masked scan's; only the updates the mask threw away (rows <= k) are
+    no longer computed.  For a non-unit L a replay step's divide is
+    computed and not used.
     """
     n = l_p.shape[0]
-    rows = jnp.arange(n)
+    t, tiles = row_tiles(n, TRSM_TILE)
+    tr = jnp.arange(t)
     lv = posit.chain_decode(l_p, fmt)
 
-    def step(b, k):
-        xk = b[k, :] if unit_diag else posit.chain_div(b[k, :], lv[k, k],
-                                                       fmt)
-        upd = posit.chain_sub(b, posit.chain_mul(lv[:, k][:, None],
-                                                 xk[None, :], fmt), fmt)
-        mask = (rows > k)[:, None]
-        b = jnp.where(mask, upd, b)
-        b = b.at[k, :].set(xk)
-        return b, None
+    def solve_tile(i, x):
+        own0, top, end = tile_rows(i, t, n)
+        g = top + tr
+        lt = jax.lax.dynamic_slice_in_dim(lv, top, t)
 
-    x, _ = jax.lax.scan(step, posit.chain_decode(b_p, fmt), jnp.arange(n))
+        def step(k, b):
+            xk = jax.lax.dynamic_index_in_dim(b, jnp.maximum(k - top, 0),
+                                              keepdims=False)
+            if not unit_diag:
+                xk = posit.chain_div(xk, lv[k, k], fmt)
+            xk = jnp.where(k >= own0, xk, x[k])
+            lk = jax.lax.dynamic_index_in_dim(lt, k, axis=1)
+            upd = posit.chain_sub(b, posit.chain_mul(lk, xk[None, :], fmt),
+                                  fmt)
+            live = ((g > k) & (g >= own0))[:, None]
+            return jnp.where(live, upd, jnp.where((g == k)[:, None], xk, b))
+
+        b = jax.lax.fori_loop(0, end, step,
+                              jax.lax.dynamic_slice_in_dim(x, top, t))
+        return jax.lax.dynamic_update_slice_in_dim(x, b, top, axis=0)
+
+    x = jax.lax.fori_loop(0, tiles, solve_tile,
+                          posit.chain_decode(b_p, fmt))
     return posit.chain_encode(x, fmt)
 
 

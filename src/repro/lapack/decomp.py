@@ -48,7 +48,9 @@ import jax.numpy as jnp
 from repro.core import posit
 from repro.core.formats import P32E2, PositFormat
 from repro.kernels.ops import rgemm
-from repro.lapack.blas import rtrsm_left_lower, rtrsm_right_lowerT
+from repro.lapack.blas import (PANEL_TILE, TRSM_TILE, row_tiles,
+                               rtrsm_left_lower, rtrsm_right_lowerT,
+                               tile_rows, trimmed_steps)
 from repro.obs import metrics as _obs_metrics
 from repro.obs import numerics as _obs_numerics
 from repro.obs import scopes as _scopes
@@ -95,32 +97,86 @@ def getf2(a_p: jax.Array, nb: int, fmt: PositFormat = P32E2):
     """Unblocked partial-pivot LU of an (m, nb) posit panel (dgetf2 order).
 
     Returns (panel, ipiv) with L strictly-below-diagonal (unit diag) and U
-    on/above.  Pivot search compares |value| — decoded posit values order
+    on/above.  Step k searches column k for its pivot, swaps rows k and
+    piv across the panel, divides column k below the diagonal by the
+    pivot, and updates A[i, c] <- A[i, c] - L[i, k] * U[k, c] for
+    i, c > k.  Pivot search compares |value| — decoded posit values order
     exactly like the word patterns (posits are monotone), and so do the
     int64 patterns of nonnegative chain values, so the pattern comparison
     picks the same pivot the word comparison did.  Fused-chain execution:
     decode once, per-op rounding in fields, encode once.
+
+    Trapezoid-trimmed, on the transposed panel (a panel column is a row,
+    so the trimmed dimension lies on sublanes): the columns walk in
+    ``blas.PANEL_TILE``-column tiles, and tile [c0, c0 + t) runs steps
+    k < c0 + t only, on its own t columns: k < c0 replays step k's row
+    swap and its update with the finished column k of L, k >= c0 factors
+    its column k.  A column left of k takes step k's swap only at the
+    end, after all steps, as nothing else touches it after its own step.
+    So each column still sees, in ascending k, the same swaps, pivot
+    search, divide and updates A[i, c] - L[i, k] * U[k, c] with the same
+    operands: the words and pivots are the masked scan's, whatever the
+    pivots, and only the updates its mask threw away (columns <= k) are
+    no longer computed.
     """
-    m = a_p.shape[0]
-    rows = jnp.arange(m)
-    a0 = posit.chain_decode(a_p, fmt)
+    m, w = a_p.shape
+    if nb != w or m < w:
+        raise ValueError(f"getf2 factors an (m, nb) panel, m >= nb; got "
+                         f"{a_p.shape} and nb={nb}")
+    t, tiles = row_tiles(w, PANEL_TILE)
+    tr, lanes = jnp.arange(t), jnp.arange(m)
+    p0 = posit.chain_decode(a_p, fmt).T          # row c: panel column c
 
-    def step(a, k):
-        col = jnp.where(rows >= k, posit.chain_abs(a[:, k]), -1)
-        col = jnp.where(posit.chain_isnan(a[:, k]), -1, col)  # NaR: never
-        piv = jnp.argmax(col).astype(jnp.int32)
-        rk, rp = a[k, :], a[piv, :]
-        a = a.at[k, :].set(rp).at[piv, :].set(rk)
-        scaled = posit.chain_div(a[:, k], a[k, k], fmt)
-        a = a.at[:, k].set(jnp.where(rows > k, scaled, a[:, k]))
-        upd = posit.chain_sub(a, posit.chain_mul(a[:, k][:, None],
-                                                 a[k, :][None, :], fmt), fmt)
-        mask = (rows > k)[:, None] & (jnp.arange(a.shape[1]) > k)[None, :]
-        a = jnp.where(mask, upd, a)
-        return a, piv
+    def factor_tile(i, carry):
+        p, ipiv = carry
+        own0, top, end = tile_rows(i, t, w)
+        g = top + tr
 
-    a, ipiv = jax.lax.scan(step, a0, jnp.arange(nb))
-    return posit.chain_encode(a, fmt), ipiv
+        def factor_column(k, r, tile, ipiv):
+            col = tile[r]
+            mag = jnp.where(lanes >= k, posit.chain_abs(col), -1)
+            mag = jnp.where(posit.chain_isnan(col), -1, mag)  # NaR: never
+            piv = jnp.argmax(mag).astype(jnp.int32)
+            tile = _swap_columns(tile, k, piv, g >= k)
+            col = tile[r]
+            scaled = posit.chain_div(col, col[k], fmt)
+            tile = tile.at[r].set(jnp.where(lanes > k, scaled, col))
+            return tile, ipiv.at[k].set(piv)
+
+        def replay_swap(k, tile, ipiv):
+            return _swap_columns(tile, k, ipiv[k], g >= own0), ipiv
+
+        def step(k, c):
+            tile, ipiv = c
+            r = jnp.maximum(k - top, 0)
+            tile, ipiv = jax.lax.cond(
+                k >= own0, functools.partial(factor_column, k, r),
+                functools.partial(replay_swap, k), tile, ipiv)
+            lk = jnp.where(k >= own0, tile[r], p[k])
+            uk = jax.lax.dynamic_index_in_dim(tile, k, axis=1)
+            upd = posit.chain_sub(tile, posit.chain_mul(lk[None, :], uk,
+                                                        fmt), fmt)
+            live = ((g > k) & (g >= own0))[:, None] & (lanes > k)[None, :]
+            return jnp.where(live, upd, tile), ipiv
+
+        tile, ipiv = jax.lax.fori_loop(
+            0, end, step, (jax.lax.dynamic_slice_in_dim(p, top, t), ipiv))
+        return jax.lax.dynamic_update_slice_in_dim(p, tile, top, 0), ipiv
+
+    p, ipiv = jax.lax.fori_loop(0, tiles, factor_tile,
+                                (p0, jnp.zeros((w,), jnp.int32)))
+    cols = jnp.arange(w)
+    p = jax.lax.fori_loop(
+        0, w, lambda k, p: _swap_columns(p, k, ipiv[k], cols < k), p)
+    return posit.chain_encode(p.T, fmt), ipiv
+
+
+def _swap_columns(x, k, p, rows):
+    """x with columns k and p exchanged in the given rows (panel rows k
+    and p, in the given panel columns)."""
+    xk, xp = x[:, k], x[:, p]
+    return (x.at[:, k].set(jnp.where(rows, xp, xk))
+            .at[:, p].set(jnp.where(rows, xk, xp)))
 
 
 # --------------------------------------------------------------------------
@@ -328,17 +384,41 @@ def rpotrf(a_p: jax.Array, nb: int = 64, gemm_backend: str = "xla_quire",
     return out
 
 
+def rank1_work(m: int, n: int, nb: int) -> dict:
+    """Element-steps of the rank-1 scans of ``rgetrf`` on an (m, n)
+    matrix at block nb: {"panel": (trimmed, masked), "trsm": (trimmed,
+    masked)}.  An element-step is one element's update computed in one
+    scan step; the masked scans computed every element of the block at
+    every step, the trimmed ones (``getf2``, ``rtrsm_left_lower``) the
+    tiles of ``blas.trimmed_steps``."""
+    work = {"panel": [0, 0], "trsm": [0, 0]}
+    mn = min(m, n)
+    for j in range(0, mn, nb):
+        w = min(nb, mn - j)
+        for part, width, tile in (("panel", m - j, PANEL_TILE),
+                                  ("trsm", n - j - w, TRSM_TILE)):
+            work[part][0] += trimmed_steps(w, tile) * width
+            work[part][1] += w * w * width
+    return {k: tuple(v) for k, v in work.items()}
+
+
 def rgetrf(a_p: jax.Array, nb: int = 64, gemm_backend: str = "xla_quire",
            fmt: PositFormat = P32E2):
     """Blocked partial-pivot LU, ONE XLA dispatch; returns (LU, ipiv).
     Observability contract as in ``rpotrf``; host span
-    ``posit.rgetrf``."""
+    ``posit.rgetrf``.  With a collector open it also counts the rank-1
+    scans' element-steps (``rank1_work``) on the host:
+    ``posit.panel.work``/``posit.panel.work_masked`` and
+    ``posit.trsm.work``/``posit.trsm.work_masked``."""
     with _obs_trace.span("posit.rgetrf", m=int(a_p.shape[0]),
                          n=int(a_p.shape[1]), nb=nb, backend=gemm_backend,
                          fmt=fmt.name):
         if not _obs_numerics.active(a_p):
             return _rgetrf_jit(a_p, nb=nb, gemm_backend=gemm_backend,
                                fmt=fmt)
+        for part, (work, masked) in rank1_work(*a_p.shape, nb).items():
+            _obs_metrics.inc(f"posit.{part}.work", work)
+            _obs_metrics.inc(f"posit.{part}.work_masked", masked)
         lu, ipiv, tel = _rgetrf_collect(a_p, nb=nb,
                                         gemm_backend=gemm_backend, fmt=fmt)
     _obs_numerics.emit_factor_steps("rgetrf", tel)

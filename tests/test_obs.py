@@ -220,6 +220,11 @@ def test_enabled_bit_identity():
     d = m.to_dict()
     # rgesv_ir factorizes through the observed rgetrf too -> 2 calls
     assert d["counters"]["rgetrf.calls"] == 2
+    work = decomp.rank1_work(n, n, 16)
+    for part in ("panel", "trsm"):
+        assert d["counters"][f"posit.{part}.work"] == 2 * work[part][0]
+        assert (d["counters"][f"posit.{part}.work_masked"]
+                == 2 * work[part][1])
     assert d["counters"]["rpotrf.calls"] == 1
     assert d["counters"]["rgeqrf.calls"] == 1
     assert len(d["series"]["rgetrf.step"]) == 6      # ceil(48/16) x 2 calls

@@ -6,8 +6,11 @@ encode), so the contract everywhere is BIT-IDENTITY, not tolerance —
 except the cross-backend rgemm parity block, where f32 accumulation is
 compared against the exact quire with the kernel's analytic error bound.
 """
-import numpy as np
+import functools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import posit as P
@@ -16,7 +19,7 @@ from repro import quire as Q
 from repro.kernels.ops import rgemm
 from repro.kernels.posit_gemm import (encode_p32_f32, posit_gemm,
                                       posit_gemm_f32)
-from repro.lapack import decomp
+from repro.lapack import blas, decomp
 from repro.lapack.blas import rtrsm_left_lower, rtrsm_right_lowerT
 
 
@@ -172,56 +175,88 @@ def test_chain_round_matches_word_roundtrip():
         assert ok.all(), (fmt.name, x[~ok][:5], got[~ok][:5], want[~ok][:5])
 
 
-def test_chain_panels_match_legacy_panels():
+def test_chain_potf2_matches_legacy_panel():
     rng = np.random.default_rng(8)
     x = rng.standard_normal((48, 48))
     sp = P.from_float64(jnp.asarray(x.T @ x))
     assert np.array_equal(np.asarray(decomp.potf2(sp)),
                           np.asarray(decomp._potf2_words(sp)))
-    g = rng.standard_normal((64, 24)) * np.exp2(rng.uniform(-6, 6, (64, 24)))
-    gp = P.from_float64(jnp.asarray(g))
-    pn, ivn = decomp.getf2(gp, 24)
-    po, ivo = decomp._getf2_words(gp, 24)
+
+
+# jitted: an eager encode of a new shape compiles op by op, for seconds
+_encode = jax.jit(P.from_float64)
+
+
+@pytest.mark.parametrize("m, w, nar", [(64, 24, None), (200, 136, None),
+                                       (80, 80, 5)])
+def test_chain_panels_match_legacy_panels(m, w, nar):
+    """getf2 against the word-domain masked panel.  At w = 136 and 80
+    the trimmed scan walks five and three column tiles (the last
+    shifted), and the pivots of the later tiles swap rows across the
+    finished ones.  A NaR column leaves no pivot candidate, so its step
+    swaps with row 0, above the diagonal; later tiles replay that swap."""
+    rng = np.random.default_rng(8)
+    g = rng.standard_normal((m, w)) * np.exp2(rng.uniform(-6, 6, (m, w)))
+    gp = _encode(jnp.asarray(g))
+    if nar is not None:
+        gp = gp.at[:, nar].set(P32E2.nar_pattern)
+    pn, ivn = decomp.getf2(gp, w)
+    po, ivo = decomp._getf2_words(gp, w)
     assert np.array_equal(np.asarray(pn), np.asarray(po))
     assert np.array_equal(np.asarray(ivn), np.asarray(ivo))
+    if w > 24:
+        t = blas.PANEL_TILE
+        assert blas.row_tiles(w, t) == (t, -(-w // t))
+    if nar is not None:
+        assert int(ivn[nar]) == 0
+    elif w > 24:
+        late = np.arange(blas.PANEL_TILE, w)
+        assert (np.asarray(ivn)[late] > late).sum() > len(late) // 2
 
 
-def test_chain_trsm_matches_word_domain():
-    """Pin the chain-form triangular solves against a per-op word-domain
-    reference (the pre-PR-2 semantics, reconstructed inline)."""
-    def mul(a, b):
-        return P.mul(a, b, P32E2, backend="fast")
+# per-op word-domain mul, sub and div, each jitted alone
+_WORD_OPS = tuple(jax.jit(functools.partial(op, fmt=P32E2, backend="fast"))
+                  for op in (P.mul, P.sub, P.div))
 
-    def sub(a, b):
-        return P.sub(a, b, P32E2, backend="fast")
 
-    def div(a, b):
-        return P.div(a, b, P32E2, backend="fast")
-
+@pytest.mark.parametrize("n, unit_diag", [(24, False), (72, False),
+                                          (72, True)])
+def test_chain_trsm_matches_word_domain(n, unit_diag):
+    """Pin the chain-form rtrsm_left_lower against a per-op word-domain
+    reference (the pre-PR-2 semantics, reconstructed inline).  At n = 72
+    the trimmed scan walks four 16-row tiles and a last one, shifted,
+    that solves 8 new rows."""
+    mul, sub, div = _WORD_OPS
     rng = np.random.default_rng(9)
-    n, m = 24, 8
+    m = 8
     l64 = np.tril(rng.standard_normal((n, n))) + 4 * np.eye(n)
     b64 = rng.standard_normal((n, m))
-    lp = P.from_float64(jnp.asarray(l64))
-    bp = P.from_float64(jnp.asarray(b64))
+    lp = _encode(jnp.asarray(l64))
+    bp = _encode(jnp.asarray(b64))
 
-    # word-domain rtrsm_left_lower (unit_diag=False), PR-1 op order
     bw = np.asarray(bp).copy()
     lw = np.asarray(lp)
     for k in range(n):
-        xk = np.asarray(div(jnp.asarray(bw[k]), jnp.asarray(lw[k, k])))
+        xk = bw[k] if unit_diag else np.asarray(
+            div(jnp.asarray(bw[k]), jnp.asarray(lw[k, k])))
         upd = np.asarray(sub(jnp.asarray(bw),
                              mul(jnp.asarray(lw[:, k][:, None]),
                                  jnp.asarray(xk[None, :]))))
         bw[k + 1:, :] = upd[k + 1:, :]
         bw[k, :] = xk
-    got = np.asarray(rtrsm_left_lower(lp, bp, unit_diag=False))
+    got = np.asarray(rtrsm_left_lower(lp, bp, unit_diag=unit_diag))
     assert np.array_equal(got, bw)
+    assert blas.row_tiles(n, blas.TRSM_TILE)[1] == (1 if n == 24 else 5)
 
-    # word-domain rtrsm_right_lowerT
-    l11 = P.from_float64(jnp.asarray(
+
+def test_chain_trsm_right_matches_word_domain():
+    """rtrsm_right_lowerT against its word-domain reference."""
+    mul, sub, div = _WORD_OPS
+    rng = np.random.default_rng(9)
+    n, m = 24, 8
+    l11 = _encode(jnp.asarray(
         np.tril(rng.standard_normal((m, m))) + 4 * np.eye(m)))
-    b2 = P.from_float64(jnp.asarray(rng.standard_normal((n, m))))
+    b2 = _encode(jnp.asarray(rng.standard_normal((n, m))))
     bw = np.asarray(b2).copy()
     lw = np.asarray(l11)
     for k in range(m):
@@ -233,6 +268,20 @@ def test_chain_trsm_matches_word_domain():
         bw[:, k] = xk
     got = np.asarray(rtrsm_right_lowerT(b2, l11))
     assert np.array_equal(got, bw)
+
+
+def test_rank1_work_shares():
+    """The trimmed scans' element-steps against the masked ones: at
+    n = 4096, nb = 256 the panel computes 36 of every 64 (32-column
+    tiles), the trsm 34 of every 64 (16-row tiles); a block of one tile
+    computes what the masked scan did."""
+    work = decomp.rank1_work(4096, 4096, 256)
+    assert work["panel"][0] / work["panel"][1] == 0.5625
+    assert work["trsm"][0] / work["trsm"][1] == 0.53125
+    assert work["trsm"][1] == 256 * 256 * sum(range(256, 4096, 256))
+    small = decomp.rank1_work(96, 96, 24)
+    assert small == {"panel": (24 * 24 * 240,) * 2,
+                     "trsm": (24 * 24 * 144,) * 2}
 
 
 # --------------------------------------------------------------------------
